@@ -21,15 +21,15 @@ roofline/kernel benches.  Prints ``name,us_per_call,derived`` CSV rows.
                          jit-recompile count across varying fleet widths
                          (core/fleet.py + the coupled chunk kernels)
   scaleout_sweep         device fan-out + precision policy: scenarios/sec
-                         vs virtual CPU device count at S in {1e3,1e4,1e5},
-                         fp64 vs mixed, via per-cell subprocesses (XLA reads
-                         the fan-out flag once at init); also writes
-                         BENCH_scaleout.json for the CI artifact trail
-  recurrence_sweep       recurrence as a cache hit: cold vs warm-process
+                         vs device count (1 up to every device jax sees) at
+                         S in {1e3,1e4,1e5}, fp64 vs mixed, all in this
+                         process; also writes BENCH_scaleout.json
+  recurrence_sweep       recurrence as a cache hit: cold vs warm
                          compile+sweep end-to-end via the disk plan cache
-                         (bar >=5x), delta_sweep slot-work ratio at S=1000
-                         for K in {1,10,100} changed schedules; writes
-                         BENCH_recurrence.json for the CI artifact trail
+                         (in-memory memo cleared between the two; bar >=5x),
+                         delta_sweep slot-work ratio at S=1000 for K in
+                         {1,10,100} changed schedules; writes
+                         BENCH_recurrence.json
   calibration_sweep      measured-run calibration: fit wall-time and
                          recovered-parameter error at U in {1e3, 1e4}
                          synthetic logged units (jax Adam vs the numpy FD
@@ -150,7 +150,7 @@ def trace_sweep():
     from repro.core import (MachineProfile, SweepCase, calibrate_workload,
                             deadline_schedule, hourly_schedule,
                             simulate_campaign)
-    from repro.core.engine_jax import _HAS_JAX, trace_sweep as run_trace
+    from repro.core.engine_jax import trace_sweep as run_trace
     from repro.core.workload import OEM_CASE_1
 
     wl, m = calibrate_workload(OEM_CASE_1, MachineProfile())
@@ -162,7 +162,7 @@ def trace_sweep():
                                    for hh in range(24)]) for i in range(S)]
         return [SweepCase(s, wl, m, carbon=trace) for s in scheds]
 
-    backend = "jax" if _HAS_JAX else "numpy"
+    backend = "jax"
     for S in (10, 120, 1000):
         cases = cases_for(S)
         run_trace(cases, backend=backend)     # warm tables + jit cache
@@ -210,11 +210,11 @@ def ensemble_sweep():
     small)."""
     from repro.core import (MachineProfile, SweepCase, calibrate_workload,
                             hourly_schedule, trace_windows)
-    from repro.core.engine_jax import (_HAS_JAX, reset_scan_stats,
-                                       scan_stats, trace_sweep as run_trace)
+    from repro.core.engine_jax import (reset_scan_stats, scan_stats,
+                                       trace_sweep as run_trace)
     from repro.core.workload import OEM_CASE_1
 
-    backend = "jax" if _HAS_JAX else "numpy"
+    backend = "jax"
     wl, m = calibrate_workload(OEM_CASE_1, MachineProfile())
 
     # --- S x E ensemble throughput -------------------------------------
@@ -279,7 +279,7 @@ def optimize_sweep():
     the jit and NumPy backends, and an end-to-end Campaign.optimize)."""
     from repro.core import (Campaign, MachineProfile, SweepCase,
                             calibrate_workload, parametric_schedule)
-    from repro.core.engine_jax import _HAS_JAX, TraceObjective
+    from repro.core.engine_jax import TraceObjective
     from repro.core.workload import OEM_CASE_1
 
     wl, m = calibrate_workload(OEM_CASE_1, MachineProfile())
@@ -287,8 +287,7 @@ def optimize_sweep():
     rng = np.random.RandomState(0)
     for N in (256, 1024):
         U = 0.05 + 0.90 * rng.rand(N, 24)
-        backends = (("jax",) if _HAS_JAX else ()) + ("numpy",)
-        for backend in backends:
+        for backend in ("jax", "numpy"):
             to = TraceObjective(case, horizon_h=280.0, backend=backend)
             to.evaluate_batch(U)          # warm tables (+ jit cache)
             us = _t(lambda: to.evaluate_batch(U), n=3, warmup=1)
@@ -301,7 +300,7 @@ def optimize_sweep():
     t0 = time.perf_counter()
     res = c.optimize("energy", deadline_h=214.0, carbon_trace=trace,
                      candidates=256, iterations=30, steps=400,
-                     method="auto" if _HAS_JAX else "cem")
+                     method="auto")
     dt = time.perf_counter() - t0
     emit("optimize_sweep/campaign_end_to_end", dt * 1e6,
          f"method={res.method}_evals={res.evaluations}_"
@@ -319,11 +318,11 @@ def fleet_sweep():
 
     from repro.core import (MachineProfile, Site, SweepCase,
                             calibrate_workload, hourly_schedule)
-    from repro.core.engine_jax import _HAS_JAX, reset_scan_stats, scan_stats
+    from repro.core.engine_jax import reset_scan_stats, scan_stats
     from repro.core.fleet import fleet_sweep as run_fleet, simulate_fleet
     from repro.core.workload import OEM_CASE_1
 
-    backend = "jax" if _HAS_JAX else "numpy"
+    backend = "jax"
     wl, m = calibrate_workload(OEM_CASE_1, MachineProfile())
     site = Site(power_cap_kw=2.0, office_kw=0.12)
 
@@ -424,11 +423,9 @@ def serving_sweep():
          f"speedup={us_loop / us_vec:.1f}x_(bar>=10x)")
 
 
-def _scaleout_worker(spec_json: str) -> None:
-    """Subprocess body for `scaleout_sweep`: one (S, devices, precision)
-    cell.  Runs in a fresh process because the virtual-device count is an
-    XLA_FLAGS setting the parent fixed *before* this interpreter imported
-    jax (see core/xla_profiles.py).  Prints a single JSON line."""
+def _scaleout_cell(S: int, devices: int, precision: str,
+                   reps: int) -> dict:
+    """One (S, devices, precision) cell of `scaleout_sweep`."""
     import dataclasses
 
     from repro.core import (MachineProfile, SweepCase, calibrate_workload,
@@ -437,9 +434,6 @@ def _scaleout_worker(spec_json: str) -> None:
                                        reset_scan_stats, scan_stats)
     from repro.core.workload import OEM_CASE_1
 
-    spec = json.loads(spec_json)
-    S, devices, precision = spec["S"], spec["devices"], spec["precision"]
-    reps = spec.get("reps", 1)
     wl, m = calibrate_workload(OEM_CASE_1, MachineProfile())
     # trim the campaign to ~2 days so one execute_plan is seconds, not
     # minutes, at S=1e5; the scan cost model (lanes x slots x buckets)
@@ -459,62 +453,40 @@ def _scaleout_worker(spec_json: str) -> None:
         execute_plan(plan, devices=devices)
     dt = (time.perf_counter() - t0) / reps
     st = scan_stats()
-    print(json.dumps({
-        "S": S, "devices": devices, "precision": precision,
-        "dt_s": dt, "scen_per_s": S / dt,
-        "devices_used": st.devices_used,
-        "precision_mode": st.precision_mode,
-        "jax_devices": len(jax.devices()),
-    }))
+    return {"S": S, "devices": devices, "precision": precision,
+            "dt_s": dt, "scen_per_s": S / dt,
+            "devices_used": st.devices_used,
+            "precision_mode": st.precision_mode}
 
 
 def scaleout_sweep():
     """Device fan-out + precision-policy scaling of the trace-scan engine
-    (acceptance trajectory: >=3x scenarios/sec at 8 virtual CPU devices,
-    S=1e5, plus a measured mixed-precision speedup with kWh/CO2 within
-    1e-6 of fp64 — pinned separately by tests/test_scaleout.py).
+    (kWh/CO2 of mixed within 1e-6 of fp64 is pinned separately by
+    tests/test_scaleout.py).
 
-    Each cell runs in a subprocess with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` because XLA
-    reads the flag exactly once at backend init.  Virtual devices share
-    the host's physical cores, so the achievable device speedup is
-    bounded by ``host_cores`` — recorded in the JSON so single-core
-    runs are not misread as regressions.  Besides the CSV rows, writes
-    machine-readable ``BENCH_scaleout.json`` (path override:
-    ``CARINA_BENCH_JSON``) for the CI artifact trail."""
-    import subprocess
-
-    from repro.core.xla_profiles import fanout_env
-
-    host_cores = os.cpu_count() or 1
+    Every cell runs in this process, on the devices jax reports: device
+    counts go from 1 up to all of them in powers of two (one process per
+    chip — a child could not reach a chip this process holds).  Every
+    row names the platform and device kind it ran on.  Besides the CSV
+    rows, writes machine-readable ``BENCH_scaleout.json`` (path
+    override: ``CARINA_BENCH_JSON``)."""
+    n_avail = len(jax.devices())
+    counts = [d for d in (1, 2, 4, 8, 16) if d <= n_avail]
     s_values = (1_000, 10_000, 100_000)
     if os.environ.get("CARINA_BENCH_FAST"):
         s_values = (1_000, 10_000)
     grid = []
     for precision in ("fp64", "mixed"):
         for S in s_values:
-            dev_counts = (1, 8)
+            dev_counts = sorted({1, counts[-1]})
             if S == s_values[-1] and precision == "fp64":
-                dev_counts = (1, 2, 4, 8)
+                dev_counts = counts
             for devices in dev_counts:
                 grid.append((precision, S, devices))
     rows = []
     for precision, S, devices in grid:
-        spec = {"S": S, "devices": devices, "precision": precision,
-                "reps": 2 if S < 100_000 else 1}
-        env = fanout_env(devices)
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(ROOT, "src"), env.get("PYTHONPATH", "")])
-        p = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "_scaleout_worker", json.dumps(spec)],
-            capture_output=True, text=True, env=env, timeout=1800)
-        if p.returncode != 0:
-            emit(f"scaleout_sweep/{precision}_S{S}_d{devices}", 0.0,
-                 f"worker_failed_rc={p.returncode}")
-            sys.stderr.write(p.stderr[-2000:] + "\n")
-            continue
-        rec = json.loads(p.stdout.strip().splitlines()[-1])
+        rec = _scaleout_cell(S, devices, precision,
+                             reps=2 if S < 100_000 else 1)
         rows.append(rec)
         emit(f"scaleout_sweep/{precision}_S{S}_d{devices}",
              rec["dt_s"] * 1e6 / S,
@@ -530,19 +502,21 @@ def scaleout_sweep():
 
     speedups = {}
     for S in s_values:
-        r1, r8 = rate("fp64", S, 1), rate("fp64", S, 8)
-        if r1 and r8:
-            speedups[f"fp64_S{S}_d8_vs_d1"] = r8 / r1
+        r1, rn = rate("fp64", S, 1), rate("fp64", S, counts[-1])
+        if r1 and rn and counts[-1] > 1:
+            speedups[f"fp64_S{S}_d{counts[-1]}_vs_d1"] = rn / r1
         rf, rm = rate("fp64", S, 1), rate("mixed", S, 1)
         if rf and rm:
             speedups[f"mixed_vs_fp64_S{S}_d1"] = rm / rf
+    dev = jax.devices()[0]
     for key, val in sorted(speedups.items()):
         emit(f"scaleout_sweep/speedup_{key}", 0.0,
-             f"x{val:.2f}_host_cores={host_cores}")
+             f"x{val:.2f}_platform={dev.platform}")
     out_path = os.environ.get("CARINA_BENCH_JSON", "BENCH_scaleout.json")
     with open(out_path, "w") as f:
-        json.dump({"bench": "scaleout_sweep", "host_cores": host_cores,
-                   "platform": jax.default_backend(),
+        json.dump({"bench": "scaleout_sweep",
+                   "device": {"platform": dev.platform,
+                              "kind": dev.device_kind, "count": n_avail},
                    "rows": rows, "speedups": speedups}, f, indent=2)
     emit("scaleout_sweep/json", 0.0, f"wrote_{out_path}_rows={len(rows)}")
 
@@ -716,19 +690,16 @@ class _ProbeHeavySchedule:
         return Decision(float(np.clip(u, 0.3, 1.0)), self.batch_size)
 
 
-def _recurrence_worker(spec_json: str) -> None:
-    """Subprocess body for `recurrence_sweep`: one full refresh cycle
-    (compile + execute + summarize) in a fresh interpreter, against a
-    shared on-disk plan cache.  Prints a single JSON line."""
+def _recurrence_cycle(S: int, cache_dir: str) -> dict:
+    """One full refresh cycle of `recurrence_sweep` (compile + execute +
+    summarize) against a shared on-disk plan cache."""
     import dataclasses
 
     from repro.core import (MachineProfile, SweepCase, calibrate_workload,
                             trace_sweep)
-    from repro.core.engine_jax import scan_stats
+    from repro.core.engine_jax import reset_scan_stats, scan_stats
     from repro.core.workload import OEM_CASE_1
 
-    spec = json.loads(spec_json)
-    S = spec["S"]
     wl, m = calibrate_workload(OEM_CASE_1, MachineProfile())
     wl = dataclasses.replace(wl, n_scenarios=400.0)
     trace = _week_trace()
@@ -736,58 +707,47 @@ def _recurrence_worker(spec_json: str) -> None:
                                            + 0.4 * i / S),
                        wl, m, carbon=trace, label=f"c{i}")
              for i in range(S)]
+    reset_scan_stats()
     t0 = time.perf_counter()
-    res = trace_sweep(cases, backend="numpy", cache_dir=spec["cache_dir"])
+    res = trace_sweep(cases, backend="numpy", cache_dir=cache_dir)
     dt = time.perf_counter() - t0
     st = scan_stats()
-    print(json.dumps({
-        "S": S, "dt_s": dt,
-        "plan_misses": st.plan_misses, "disk_hits": st.disk_hits,
-        "co2_sum": sum(r.co2_kg for r in res)}))
+    return {"S": S, "dt_s": dt,
+            "plan_misses": st.plan_misses, "disk_hits": st.disk_hits,
+            "co2_sum": sum(r.co2_kg for r in res)}
 
 
 def recurrence_sweep():
-    """Recurrence as a cache hit (ISSUE 9): cold vs warm-process
-    compile+sweep end-to-end (acceptance: >=5x — the warm process reads
-    compiled tables off disk instead of re-probing S python schedules),
-    plus the `delta_sweep` slot-work ratio at S=1000 for K changed
-    schedules in {1, 10, 100} (acceptance at K=1, S=100: <=2% —
-    pinned by tests/test_plancache.py; here the ratio is reported at
-    production batch width).  Writes ``BENCH_recurrence.json`` (path
-    override: ``CARINA_BENCH_RECURRENCE_JSON``)."""
+    """Recurrence as a cache hit (ISSUE 9): cold vs warm compile+sweep
+    end-to-end (acceptance: >=5x — the warm cycle reads compiled tables
+    off disk instead of re-probing S python schedules; the in-memory
+    plan memo is cleared between the two, so only the disk cache
+    carries over, as it does into a fresh process), plus the
+    `delta_sweep` slot-work ratio at S=1000 for K changed schedules in
+    {1, 10, 100} (acceptance at K=1, S=100: <=2% — pinned by
+    tests/test_plancache.py; here the ratio is reported at production
+    batch width).  Writes ``BENCH_recurrence.json`` (path override:
+    ``CARINA_BENCH_RECURRENCE_JSON``)."""
     import dataclasses
     import shutil
-    import subprocess
     import tempfile
 
     from repro.core import (MachineProfile, SweepCase, calibrate_workload,
                             constant_schedule)
-    from repro.core.engine_jax import (compile_plan, delta_sweep,
-                                       execute_plan, reset_scan_stats,
-                                       scan_stats, summarize_plan)
+    from repro.core.engine_jax import (clear_plan_cache, compile_plan,
+                                       delta_sweep, execute_plan,
+                                       reset_scan_stats, scan_stats,
+                                       summarize_plan)
     from repro.core.workload import OEM_CASE_1
 
     fast = bool(os.environ.get("CARINA_BENCH_FAST"))
     S_cycle = 24 if fast else 64
     cache_dir = tempfile.mkdtemp(prefix="carina-plancache-")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(ROOT, "src"), env.get("PYTHONPATH", "")])
-    env.pop("CARINA_PLAN_CACHE", None)
     runs = {}
     try:
         for label in ("cold", "warm"):
-            spec = {"S": S_cycle, "cache_dir": cache_dir}
-            p = subprocess.run(
-                [sys.executable, os.path.abspath(__file__),
-                 "_recurrence_worker", json.dumps(spec)],
-                capture_output=True, text=True, env=env, timeout=1800)
-            if p.returncode != 0:
-                emit(f"recurrence_sweep/{label}_S{S_cycle}", 0.0,
-                     f"worker_failed_rc={p.returncode}")
-                sys.stderr.write(p.stderr[-2000:] + "\n")
-                return
-            runs[label] = json.loads(p.stdout.strip().splitlines()[-1])
+            clear_plan_cache()
+            runs[label] = _recurrence_cycle(S_cycle, cache_dir)
             emit(f"recurrence_sweep/{label}_S{S_cycle}",
                  runs[label]["dt_s"] * 1e6,
                  f"plan_misses={runs[label]['plan_misses']}_"
@@ -975,12 +935,6 @@ BENCHES = {
 
 def main(argv=None) -> None:
     """Run the named benchmarks (all of them with no arguments)."""
-    if argv and argv[0] == "_scaleout_worker":
-        _scaleout_worker(argv[1])
-        return
-    if argv and argv[0] == "_recurrence_worker":
-        _recurrence_worker(argv[1])
-        return
     names = argv if argv else list(BENCHES)
     unknown = [n for n in names if n not in BENCHES]
     if unknown:

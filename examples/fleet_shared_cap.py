@@ -60,7 +60,6 @@ def main():
     print("=== fleet-wide assignments (grouped-lane sweep, coupled)")
     print(f"  engine: devices_used={st.devices_used} "
           f"precision={st.precision_mode or 'fp64'} "
-          f"pallas_dispatches={st.pallas_dispatches} "
           f"chunks={st.chunks} jit_shapes={st.jit_compiles}")
     for fr in rows:
         print(f"  {fr.policy:28s} {fmt(fr)}")
@@ -109,9 +108,8 @@ def main():
     st = carina.scan_stats()
     print(f"engine totals: devices_used={st.devices_used} "
           f"precision={st.precision_mode or 'fp64'} "
-          f"pallas_dispatches={st.pallas_dispatches} "
           f"chunks={st.chunks} jit_shapes={st.jit_compiles} "
-          "(scale-out knobs: Fleet.sweep(devices=, precision=, pallas=))")
+          "(scale-out knobs: Fleet.sweep(devices=, precision=))")
 
 
 if __name__ == "__main__":

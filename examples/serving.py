@@ -64,7 +64,6 @@ def main():
     st = scan_stats()
     print(f"  engine: devices_used={st.devices_used} "
           f"precision={st.precision_mode or 'fp64'} "
-          f"pallas_dispatches={st.pallas_dispatches} "
           f"requests_seen={st.requests_seen} "
           "(live ticks are accounted directly; window-mode sweeps run "
           "through execute_plan and report its scale-out counters here)")

@@ -1,13 +1,9 @@
-"""Cross-version jax API aliases.
+"""jax process settings shared by the engine and the model stack.
 
-`shard_map` moved from `jax.experimental.shard_map` to the jax namespace
-and renamed its replication-check kwarg (`check_rep` -> `check_vma`).
-Import it from here with the new-style `check_vma` spelling and it works
-on both sides of the move.  `axis_size` appeared in jax.lax later than
-`axis_index`; the fallback is the standard psum-of-ones identity.
-`enable_x64` is the double-precision context manager; implemented here
-over the config flag with an explicit frame stack so nested and
-out-of-order exits restore the right value on every jax version.
+`enable_x64` is the double-precision context manager, implemented over
+the config flag with an explicit frame stack so nested and out-of-order
+exits restore the right value.  `enable_persistent_compilation_cache`
+decides where compiled programs are cached.
 """
 from __future__ import annotations
 
@@ -63,55 +59,31 @@ def enable_x64(new_val: bool = True):
                 break
 
 
-# Active persistent-compilation-cache directory (None = not enabled).
-_compilation_cache_dir = None
+#: The persistent compilation cache's directory when
+#: ``JAX_COMPILATION_CACHE_DIR`` is not set: a fixed path inside the
+#: checkout (git-ignored), never a temporary one: a cache that moves
+#: between runs is never found again.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 
-def enable_persistent_compilation_cache(cache_dir=None):
-    """Point jax's persistent compilation cache at a directory.
+def enable_persistent_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
 
-    The plan cache (core/plancache.py) removes re-*staging* across
-    processes but a fresh process still pays every XLA compile; jax's
-    own persistent cache closes that gap.  `CARINA_JAX_CACHE` (env)
-    wins over `cache_dir`; with neither set this is a no-op returning
-    None.  The min-entry-size/min-compile-time floors are dropped so
-    even the engine's small chunk kernels are cached — CARINA's
-    kernels are many and cheap, which is exactly the population the
-    default floors exclude.  Idempotent (re-pointing at the active
-    directory is free) and soft-failing: a jax too old to have the
-    config knobs just leaves the cache off.
+    The one place that decides where compiled programs are cached; the
+    engine calls it before its first compile.  When
+    ``JAX_COMPILATION_CACHE_DIR`` is set the cache goes there and to no
+    other directory; otherwise it goes to
+    `DEFAULT_COMPILATION_CACHE_DIR`.  The min-entry-size and
+    min-compile-time floors are dropped so the engine's many small chunk
+    kernels are cached too.  The plan cache (core/plancache.py) removes
+    re-*staging* across processes; this removes the re-compiles.
     """
-    global _compilation_cache_dir
-    target = os.environ.get("CARINA_JAX_CACHE") or cache_dir
-    if not target:
-        return None
-    target = os.path.abspath(target)
-    if _compilation_cache_dir == target:
-        return target
-    try:
-        os.makedirs(target, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", target)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        return None
-    _compilation_cache_dir = target
+    target = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+              or DEFAULT_COMPILATION_CACHE_DIR)
+    os.makedirs(target, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", target)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return target
-
-
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-    def axis_size(axis_name):
-        return jax.lax.psum(1, axis_name)
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)

@@ -18,10 +18,10 @@ drives both.  Parameters are fitted in log space
 is conditioned on *relative* moves, so watts-scale and unitless
 parameters share one learning rate.
 
-A NumPy fallback (`_fd_adam`, deterministic central differences + the
-same Adam update) keeps calibration working where jax is unavailable;
-bootstrap confidence intervals resample units via multinomial weights
-(so no array re-gather, and the numpy refits are cheap).
+A NumPy path (`_fd_adam`, deterministic central differences + the
+same Adam update) is the explicit `backend="numpy"` choice; bootstrap
+confidence intervals resample units via multinomial weights (so no
+array re-gather, and the numpy refits are cheap).
 
 Surfaced as `Campaign.calibrate(log_path=...)`; pinned by the
 round-trip test (simulate with known params -> log -> fit recovers them
@@ -186,7 +186,7 @@ class CalibrationObjective:
 
 def _fd_adam(loss, p0, steps: int, lr: float, eps: float = 1e-5
              ) -> Tuple[np.ndarray, List[float]]:
-    """Deterministic central-difference Adam: the NumPy fallback mirror
+    """Deterministic central-difference Adam: the NumPy-backend mirror
     of `optimize._grad_search` (same moments, same 10.0 norm clip, best
     parameters seen returned — the loss is nonconvex)."""
     b1, b2, adam_eps = 0.9, 0.999, 1e-8
@@ -233,13 +233,7 @@ def _resolve_backend(backend: Optional[str]) -> str:
     if backend not in (None, "jax", "numpy"):
         raise ValueError(f"backend must be 'jax' or 'numpy', got "
                          f"{backend!r}")
-    if backend is not None:
-        return backend
-    try:
-        import jax  # noqa: F401
-        return "jax"
-    except Exception:
-        return "numpy"
+    return backend or "jax"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -284,7 +278,7 @@ def fit_calibration(obs: Observations, workload, machine, *,
 
     The point estimate runs on `backend` ("jax" = Adam through
     `jax.value_and_grad` via `optimize._grad_search`; "numpy" = the
-    deterministic finite-difference mirror; None = jax when available).
+    deterministic finite-difference mirror; None = jax).
     `bootstrap` > 0 adds seeded unit-resampling confidence intervals:
     each replicate reweights units by a multinomial draw and refits on
     the (cheap, compile-free) numpy path, warm-started from the point

@@ -15,7 +15,7 @@ Two vectorized paths sit behind one `sweep()` entry point:
     grid cannot represent — progress/elapsed-aware schedules, non-periodic
     multi-day `TraceSignal`s, carbon ensembles (`SignalEnsemble`),
     sub-hour band edges — is compiled into a `SweepPlan` and stepped
-    through a chunked resumable `jax.lax.scan` (NumPy fallback) that
+    through a chunked resumable `jax.lax.scan` (NumPy reference) that
     carries `(remaining, elapsed, accumulator)` state across fixed-shape
     horizon chunks.
 

@@ -45,44 +45,33 @@ schedules get their table rows built chunk by chunk, never for slots
 already scanned.  Physics per slot comes from the shared rate model
 (core/model.py) with `xp=jnp`.
 
-JAX is optional: with `backend="numpy"` (or when JAX is absent, following
-the repro/compat.py guard pattern) the identical scan runs as a NumPy
-loop over the grid — still vectorized across lanes, just not jitted.
-JAX runs under `enable_x64` so both backends agree to float64 precision
-with the periodic engine on periodic cases.
+The scan runs on JAX's default device.  `backend="numpy"` is the host
+reference: the identical scan as a NumPy loop over the grid — still
+vectorized across lanes, just not jitted.  JAX runs under `enable_x64`
+so both backends agree to float64 precision with the periodic engine on
+periodic cases.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import math
-import os
 from collections import OrderedDict
 from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
                     Sequence, Set, Tuple)
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, PartitionSpec
 
+from repro.compat import enable_persistent_compilation_cache, enable_x64
 from repro.core import model, plancache
 from repro.core.carbon import GridCarbonModel
 from repro.core.schedule import SchedulingContext, as_schedule
 from repro.core.signal import (Signal, SignalEnsemble, carbon_signal,
                                sample_signal)
 from repro.core.simulator import SimResult, ensemble_stats
-
-try:                                    # JAX is optional on the trace path
-    import jax
-    import jax.numpy as jnp
-
-    from repro.compat import (enable_persistent_compilation_cache,
-                              enable_x64)
-    _HAS_JAX = True
-except Exception:                       # pragma: no cover - env without jax
-    jax = jnp = enable_x64 = None
-    _HAS_JAX = False
-
-    def enable_persistent_compilation_cache(cache_dir=None):
-        return None                     # nothing to cache without jax
 
 _PROBE_PROGRESS = (0.0, 1.0 / 3.0, 2.0 / 3.0, 0.999)
 _PROBE_OFFSETS = (0.0, 3.0, 5.0, 9.0, 13.0, 17.0, 21.0)
@@ -130,8 +119,8 @@ class ScanStats:
     fan-out any chunk executed on (0 until a scan runs, 1 for purely
     single-device scans); `precision_mode` is the dtype policy
     ("fp64"/"mixed") of the most recent `execute_plan`; and
-    `pallas_dispatches` counts launches of the coupled-throttle Pallas
-    kernel (0 whenever the jnp fallback ran instead).
+    `bytes_uploaded` counts the host -> device bytes the chunk launches
+    moved (inputs and carried state; divide by `chunks` for per-chunk).
     MPC observability: `replans` counts `replace_tables` calls (one per
     mid-flight re-plan) and `slots_reused` counts the lane x slot units
     of already-executed state carried across those re-plans — work a
@@ -164,7 +153,7 @@ class ScanStats:
     requests_degraded: int = 0    # ... admitted at a cheaper tier
     devices_used: int = 0         # max devices any chunk sharded across
     precision_mode: str = ""      # dtype policy of the last executed plan
-    pallas_dispatches: int = 0    # coupled-chunk Pallas kernel launches
+    bytes_uploaded: int = 0       # host -> device bytes of chunk launches
     jit_shapes: Set[tuple] = dataclasses.field(default_factory=set)
 
     @property
@@ -212,7 +201,7 @@ def reset_scan_stats() -> None:
     _STATS.requests_degraded = 0
     _STATS.devices_used = 0
     _STATS.precision_mode = ""
-    _STATS.pallas_dispatches = 0
+    _STATS.bytes_uploaded = 0
     _STATS.jit_shapes = set()
 
 
@@ -919,11 +908,8 @@ def compile_plan(cases: Sequence, price: Optional[Signal] = None, *,
                 for c, ens in zip(cases, ensembles)]
 
     cache = plancache.get_cache(cache_dir)
-    # fresh-process warm starts should skip XLA compiles too, not just
-    # plan staging: point jax's persistent compilation cache at a
-    # sibling of the plan store ("<root>/xla"; CARINA_JAX_CACHE wins)
-    enable_persistent_compilation_cache(
-        os.path.join(cache.root, "xla") if cache is not None else None)
+    # fresh-process warm starts skip XLA compiles too, not just staging
+    enable_persistent_compilation_cache()
     memo: dict = {}
     keys = [_fingerprint(c, price, sph, B, max_days, memo) for c in cases]
     compiled: List[Optional[_CaseCompiled]] = [
@@ -1349,7 +1335,7 @@ def delta_sweep(prev_plan: SweepPlan, prev_results: Sequence[SimResult], *,
                 schedules=None, carbon=None,
                 backend: Optional[str] = None,
                 chunk_days: Optional[int] = None,
-                devices: Optional[int] = None, pallas=None,
+                devices: Optional[int] = None,
                 cache_dir: Optional[str] = None) -> DeltaSweepResult:
     """Re-sweep a recurring batch incrementally: re-scan only the cases
     a delta actually affects and splice last cycle's `SimResult`s for
@@ -1414,7 +1400,7 @@ def delta_sweep(prev_plan: SweepPlan, prev_results: Sequence[SimResult], *,
     _STATS.lanes_recomputed += subplan.n_lanes
     _STATS.lanes_spliced += new_plan.n_lanes - subplan.n_lanes
     state = execute_plan(subplan, backend=backend, chunk_days=chunk_days,
-                         devices=devices, pallas=pallas)
+                         devices=devices)
     sub_results = summarize_plan(subplan, state)
     results = prev_results
     for j, i in enumerate(sub):
@@ -1555,131 +1541,129 @@ def _scan_chunk_np_coupled(u_tab, b_tab, rowidx, bg, cf, pr, lens,
     return remaining, rt, kwh, co2, cost, speak
 
 
-if _HAS_JAX:
-    def _scan_chunk_jax_impl(u_tab, b_tab, rowidx, bg, cf, pr, lens,
-                             remaining, rt, kwh, co2, cost,
-                             n_scen, rate, oh, idle, dyn, alpha, gamma,
-                             ohfrac, B: int):
-        A = u_tab.shape[0]
-        sidx = jnp.arange(A)
+def _scan_chunk_jax_impl(u_tab, b_tab, rowidx, bg, cf, pr, lens,
+                         remaining, rt, kwh, co2, cost,
+                         n_scen, rate, oh, idle, dyn, alpha, gamma,
+                         ohfrac, B: int):
+    A = u_tab.shape[0]
+    sidx = jnp.arange(A)
 
-        def step(carry, xs):
-            remaining, rt, kwh, co2, cost = carry
-            row, bg_t, cf_t, pr_t, ln = xs          # cf_t: (A, E)
-            # mixed precision: the lookup/rates run at the tables' dtype
-            # while the carried state stays fp64 (no-op cast on fp64)
-            prog = (1.0 - remaining / n_scen).astype(u_tab.dtype)
-            u, bt = _bucket_lookup(jnp, u_tab, b_tab, sidx, row, prog, B)
-            r = model.rates(u, bt, bg_t, rate_at_full=rate,
-                            batch_overhead_s=oh, idle_w=idle, dyn_w=dyn,
-                            alpha=alpha, gamma=gamma, overhead_w_frac=ohfrac,
-                            xp=jnp)
-            dt = jnp.where(
-                remaining > 0.0,
-                jnp.minimum(ln, remaining / jnp.maximum(r.scen_per_s, 1e-30)),
-                0.0)
-            e = r.kwh_per_s * dt
-            carry = (remaining - r.scen_per_s * dt, rt + dt, kwh + e,
-                     co2 + e[:, None] * cf_t, cost + e * pr_t)
-            return carry, None
+    def step(carry, xs):
+        remaining, rt, kwh, co2, cost = carry
+        row, bg_t, cf_t, pr_t, ln = xs          # cf_t: (A, E)
+        # mixed precision: the lookup/rates run at the tables' dtype
+        # while the carried state stays fp64 (no-op cast on fp64)
+        prog = (1.0 - remaining / n_scen).astype(u_tab.dtype)
+        u, bt = _bucket_lookup(jnp, u_tab, b_tab, sidx, row, prog, B)
+        r = model.rates(u, bt, bg_t, rate_at_full=rate,
+                        batch_overhead_s=oh, idle_w=idle, dyn_w=dyn,
+                        alpha=alpha, gamma=gamma, overhead_w_frac=ohfrac,
+                        xp=jnp)
+        dt = jnp.where(
+            remaining > 0.0,
+            jnp.minimum(ln, remaining / jnp.maximum(r.scen_per_s, 1e-30)),
+            0.0)
+        e = r.kwh_per_s * dt
+        carry = (remaining - r.scen_per_s * dt, rt + dt, kwh + e,
+                 co2 + e[:, None] * cf_t, cost + e * pr_t)
+        return carry, None
 
-        init = (remaining, rt, kwh, co2, cost)
-        xs = (rowidx.T, bg.T, cf.transpose(2, 0, 1), pr.T, lens.T)
-        final, _ = jax.lax.scan(step, init, xs)
-        return final
+    init = (remaining, rt, kwh, co2, cost)
+    xs = (rowidx.T, bg.T, cf.transpose(2, 0, 1), pr.T, lens.T)
+    final, _ = jax.lax.scan(step, init, xs)
+    return final
 
-    _scan_chunk_jax = functools.partial(
-        jax.jit, static_argnames=("B",))(_scan_chunk_jax_impl)
 
-    def _scan_chunk_jax_coupled_impl(u_tab, b_tab, rowidx, bg, cf, pr,
-                                     lens, gid, cap_g, office,
-                                     remaining, rt, kwh, co2, cost, speak,
-                                     n_scen, rate, oh, idle, dyn, alpha,
-                                     gamma, ohfrac, B: int, G: int):
-        A = u_tab.shape[0]
-        sidx = jnp.arange(A)
+_scan_chunk_jax = functools.partial(
+    jax.jit, static_argnames=("B",))(_scan_chunk_jax_impl)
 
-        def step(carry, xs):
-            remaining, rt, kwh, co2, cost, speak = carry
-            row, bg_t, cf_t, pr_t, ln, off_t = xs      # off_t: (G,)
-            prog = (1.0 - remaining / n_scen).astype(u_tab.dtype)
-            u, bt = _bucket_lookup(jnp, u_tab, b_tab, sidx, row, prog, B)
-            r = model.rates(u, bt, bg_t, rate_at_full=rate,
-                            batch_overhead_s=oh, idle_w=idle, dyn_w=dyn,
-                            alpha=alpha, gamma=gamma, overhead_w_frac=ohfrac,
-                            xp=jnp)
-            active = remaining > _FINISH_FRAC * n_scen
-            base_lane = jnp.where(
-                active, model.power_w(bg_t, idle, dyn, alpha, xp=jnp),
-                0.0) / 1000.0
-            base = jnp.zeros(G, base_lane.dtype).at[gid].add(base_lane)
-            head = cap_g - off_t
-            f = jnp.ones(G, base_lane.dtype)
-            r2 = r
-            for _ in range(model.SITE_THROTTLE_ITERS):
-                draw = jnp.zeros(G, base_lane.dtype).at[gid].add(
-                    jnp.where(active, r2.p_avg_w, 0.0) / 1000.0)
-                f = model.site_throttle(draw, base, head, f, xp=jnp)
-                r2 = model.rates(u * f[gid], bt, bg_t, rate_at_full=rate,
-                                 batch_overhead_s=oh, idle_w=idle,
-                                 dyn_w=dyn, alpha=alpha, gamma=gamma,
-                                 overhead_w_frac=ohfrac, xp=jnp)
-            dt = jnp.where(
-                remaining > 0.0,
-                jnp.minimum(ln,
-                            remaining / jnp.maximum(r2.scen_per_s, 1e-30)),
-                0.0)
-            e = r2.kwh_per_s * dt
-            site_kw = jnp.zeros(G, base_lane.dtype).at[gid].add(
-                jnp.where(active, r2.p_avg_w, 0.0) / 1000.0) + off_t
-            speak = jnp.where(active, jnp.maximum(speak, site_kw[gid]),
-                              speak)
-            carry = (remaining - r2.scen_per_s * dt, rt + dt, kwh + e,
-                     co2 + e[:, None] * cf_t, cost + e * pr_t, speak)
-            return carry, None
 
-        init = (remaining, rt, kwh, co2, cost, speak)
-        xs = (rowidx.T, bg.T, cf.transpose(2, 0, 1), pr.T, lens.T, office.T)
-        final, _ = jax.lax.scan(step, init, xs)
-        return final
+def _scan_chunk_jax_coupled_impl(u_tab, b_tab, rowidx, bg, cf, pr,
+                                 lens, gid, cap_g, office,
+                                 remaining, rt, kwh, co2, cost, speak,
+                                 n_scen, rate, oh, idle, dyn, alpha,
+                                 gamma, ohfrac, B: int, G: int):
+    A = u_tab.shape[0]
+    sidx = jnp.arange(A)
 
-    _scan_chunk_jax_coupled = functools.partial(
-        jax.jit, static_argnames=("B", "G"))(_scan_chunk_jax_coupled_impl)
+    def step(carry, xs):
+        remaining, rt, kwh, co2, cost, speak = carry
+        row, bg_t, cf_t, pr_t, ln, off_t = xs      # off_t: (G,)
+        prog = (1.0 - remaining / n_scen).astype(u_tab.dtype)
+        u, bt = _bucket_lookup(jnp, u_tab, b_tab, sidx, row, prog, B)
+        r = model.rates(u, bt, bg_t, rate_at_full=rate,
+                        batch_overhead_s=oh, idle_w=idle, dyn_w=dyn,
+                        alpha=alpha, gamma=gamma, overhead_w_frac=ohfrac,
+                        xp=jnp)
+        active = remaining > _FINISH_FRAC * n_scen
+        base_lane = jnp.where(
+            active, model.power_w(bg_t, idle, dyn, alpha, xp=jnp),
+            0.0) / 1000.0
+        base = jnp.zeros(G, base_lane.dtype).at[gid].add(base_lane)
+        head = cap_g - off_t
+        f = jnp.ones(G, base_lane.dtype)
+        r2 = r
+        for _ in range(model.SITE_THROTTLE_ITERS):
+            draw = jnp.zeros(G, base_lane.dtype).at[gid].add(
+                jnp.where(active, r2.p_avg_w, 0.0) / 1000.0)
+            f = model.site_throttle(draw, base, head, f, xp=jnp)
+            r2 = model.rates(u * f[gid], bt, bg_t, rate_at_full=rate,
+                             batch_overhead_s=oh, idle_w=idle,
+                             dyn_w=dyn, alpha=alpha, gamma=gamma,
+                             overhead_w_frac=ohfrac, xp=jnp)
+        dt = jnp.where(
+            remaining > 0.0,
+            jnp.minimum(ln,
+                        remaining / jnp.maximum(r2.scen_per_s, 1e-30)),
+            0.0)
+        e = r2.kwh_per_s * dt
+        site_kw = jnp.zeros(G, base_lane.dtype).at[gid].add(
+            jnp.where(active, r2.p_avg_w, 0.0) / 1000.0) + off_t
+        speak = jnp.where(active, jnp.maximum(speak, site_kw[gid]),
+                          speak)
+        carry = (remaining - r2.scen_per_s * dt, rt + dt, kwh + e,
+                 co2 + e[:, None] * cf_t, cost + e * pr_t, speak)
+        return carry, None
 
-    @functools.lru_cache(maxsize=64)
-    def _sharded_plain(n_dev: int, B: int):
-        """Jitted `shard_map` wrapper of the plain chunk kernel: every
-        argument (and every output) is a lane-leading array split along
-        the mesh's "lanes" axis, so the scan runs embarrassingly
-        parallel — zero cross-device communication, and each lane's
-        arithmetic is bitwise-identical to the single-device kernel."""
-        from jax.sharding import Mesh, PartitionSpec
+    init = (remaining, rt, kwh, co2, cost, speak)
+    xs = (rowidx.T, bg.T, cf.transpose(2, 0, 1), pr.T, lens.T, office.T)
+    final, _ = jax.lax.scan(step, init, xs)
+    return final
 
-        from repro.compat import shard_map
-        mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("lanes",))
-        spec = PartitionSpec("lanes")
-        fn = shard_map(functools.partial(_scan_chunk_jax_impl, B=B),
+
+_scan_chunk_jax_coupled = functools.partial(
+    jax.jit, static_argnames=("B", "G"))(_scan_chunk_jax_coupled_impl)
+
+
+@functools.lru_cache(maxsize=64)
+def _sharded_plain(n_dev: int, B: int):
+    """Jitted `shard_map` wrapper of the plain chunk kernel: every
+    argument (and every output) is a lane-leading array split along
+    the mesh's "lanes" axis, so the scan runs embarrassingly
+    parallel — zero cross-device communication, and each lane's
+    arithmetic is bitwise-identical to the single-device kernel."""
+    mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("lanes",))
+    spec = PartitionSpec("lanes")
+    fn = jax.shard_map(functools.partial(_scan_chunk_jax_impl, B=B),
                        mesh=mesh, in_specs=(spec,) * 20,
                        out_specs=(spec,) * 5, check_vma=False)
-        return jax.jit(fn)
+    return jax.jit(fn)
 
-    @functools.lru_cache(maxsize=64)
-    def _sharded_coupled(n_dev: int, B: int, G: int):
-        """Jitted `shard_map` wrapper of the coupled chunk kernel.  The
-        caller partitions lanes at *group* boundaries (groups are
-        contiguous in lane order) and stacks per-device blocks, so each
-        device's segment-sum sees only its own G=`G` local groups and
-        the site-cap fixed point never crosses a shard."""
-        from jax.sharding import Mesh, PartitionSpec
 
-        from repro.compat import shard_map
-        mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("lanes",))
-        spec = PartitionSpec("lanes")
-        fn = shard_map(functools.partial(_scan_chunk_jax_coupled_impl,
+@functools.lru_cache(maxsize=64)
+def _sharded_coupled(n_dev: int, B: int, G: int):
+    """Jitted `shard_map` wrapper of the coupled chunk kernel.  The
+    caller partitions lanes at *group* boundaries (groups are
+    contiguous in lane order) and stacks per-device blocks, so each
+    device's segment-sum sees only its own G=`G` local groups and
+    the site-cap fixed point never crosses a shard."""
+    mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("lanes",))
+    spec = PartitionSpec("lanes")
+    fn = jax.shard_map(functools.partial(_scan_chunk_jax_coupled_impl,
                                          B=B, G=G),
                        mesh=mesh, in_specs=(spec,) * 24,
                        out_specs=(spec,) * 6, check_vma=False)
-        return jax.jit(fn)
+    return jax.jit(fn)
 
 
 def _pad_pow2(n: int, minimum: int = 8) -> int:
@@ -1719,7 +1703,7 @@ def _resolve_devices(devices, use_jax: bool) -> int:
     `devices=None` auto-fans across every local device; an explicit
     count is clamped to what the platform exposes.  The NumPy backend
     is always single-device."""
-    if not use_jax or not _HAS_JAX:
+    if not use_jax:
         return 1
     avail = len(jax.devices())
     if devices is None:
@@ -1730,50 +1714,16 @@ def _resolve_devices(devices, use_jax: bool) -> int:
     return min(n, avail)
 
 
-@functools.lru_cache(maxsize=1)
-def _pallas_available() -> bool:
-    """Can the coupled-throttle Pallas kernel be imported at all?"""
-    try:
-        import repro.kernels.coupled_throttle  # noqa: F401
-    except Exception:
-        return False
-    return True
-
-
-def _resolve_pallas(pallas, use_jax: bool) -> str:
-    """Resolve the Pallas dispatch policy to "off"/"on"/"interpret".
-
-    `pallas=None` defers to the ``CARINA_PALLAS`` environment variable
-    (default "auto": compiled Pallas on TPU backends, jnp fallback
-    elsewhere).  `True`/"on" forces the kernel — in interpreter mode on
-    non-TPU backends, where Pallas has no compiled lowering;
-    "interpret" forces interpreter mode everywhere (the test pin path);
-    `False`/"off" disables.  Whenever the kernel module is unavailable
-    the answer is "off" — the jnp kernel is always a clean fallback."""
-    if pallas is None:
-        pallas = os.environ.get("CARINA_PALLAS", "auto")
-    if pallas is True:
-        pallas = "on"
-    elif pallas is False:
-        pallas = "off"
-    pallas = str(pallas).lower()
-    if pallas not in ("auto", "on", "off", "interpret"):
-        raise ValueError(f"unknown pallas policy {pallas!r}; use "
-                         "'auto', 'on', 'off' or 'interpret'")
-    if pallas == "off" or not use_jax or not _HAS_JAX:
-        return "off"
-    if pallas == "auto":
-        pallas = "on" if jax.default_backend() == "tpu" else "off"
-    if pallas == "off" or not _pallas_available():
-        return "off"
-    if pallas == "on" and jax.default_backend() != "tpu":
-        return "interpret"
-    return pallas
+def _upload(*pairs) -> tuple:
+    """(host array, dtype) pairs -> device arrays, counting the bytes
+    moved (call under `enable_x64`; dtype None keeps the array's own)."""
+    out = tuple(jnp.asarray(a, dt) for a, dt in pairs)
+    _STATS.bytes_uploaded += sum(x.nbytes for x in out)
+    return out
 
 
 def _run_chunk(plan: SweepPlan, active: np.ndarray, inputs, state_slices,
-               use_jax: bool, n_dev: int = 1,
-               pallas: str = "off") -> tuple:
+               use_jax: bool, n_dev: int = 1) -> tuple:
     """Execute one chunk for the active lanes, padding the batch to
     bucketed shapes on the JAX backend so repeated sweeps reuse the
     compiled kernel instead of recompiling per exact size.
@@ -1788,7 +1738,7 @@ def _run_chunk(plan: SweepPlan, active: np.ndarray, inputs, state_slices,
     fp64 accumulators ride along either way."""
     if plan.coupled:
         return _run_chunk_coupled(plan, active, inputs, state_slices,
-                                  use_jax, n_dev, pallas)
+                                  use_jax, n_dev)
     u_tab, b_tab, rowidx, bg, cf, pr, lens = inputs
     A, C = rowidx.shape
     Bg = u_tab.shape[2]
@@ -1831,12 +1781,10 @@ def _run_chunk(plan: SweepPlan, active: np.ndarray, inputs, state_slices,
     _STATS.slot_work += Ap * C
     _STATS.devices_used = max(_STATS.devices_used, n_dev)
     with enable_x64():
-        ins = (jnp.asarray(u_tab, cdt), jnp.asarray(b_tab, cdt),
-               jnp.asarray(rowidx), jnp.asarray(bg, cdt),
-               jnp.asarray(cf, cdt), jnp.asarray(pr, cdt),
-               jnp.asarray(lens, cdt))
-        st = tuple(jnp.asarray(a, adt) for a in state_slices)
-        sc = tuple(jnp.asarray(a, cdt) for a in scalars)
+        ins = _upload((u_tab, cdt), (b_tab, cdt), (rowidx, None), (bg, cdt),
+                      (cf, cdt), (pr, cdt), (lens, cdt))
+        st = _upload(*((a, adt) for a in state_slices))
+        sc = _upload(*((a, cdt) for a in scalars))
         if n_dev > 1:
             out = _sharded_plain(n_dev, Bg)(*ins, *st, *sc)
         else:
@@ -1848,8 +1796,7 @@ def _run_chunk(plan: SweepPlan, active: np.ndarray, inputs, state_slices,
 
 
 def _run_chunk_coupled(plan: SweepPlan, active: np.ndarray, inputs,
-                       state_slices, use_jax: bool, n_dev: int = 1,
-                       pallas: str = "off") -> tuple:
+                       state_slices, use_jax: bool, n_dev: int = 1) -> tuple:
     """One chunk through the grouped site-coupled kernel.
 
     Active lanes' groups are remapped to dense ids (finished groups
@@ -1860,9 +1807,7 @@ def _run_chunk_coupled(plan: SweepPlan, active: np.ndarray, inputs,
 
     Device fan-out splits lanes at *group* boundaries only (`n_dev` is
     clamped to the live group count), so the site-cap segment-sum and
-    throttle fixed point stay device-local.  On a single device the
-    coupled step can instead dispatch to the Pallas kernel
-    (kernels/coupled_throttle.py) per the resolved `pallas` policy."""
+    throttle fixed point stay device-local."""
     u_tab, b_tab, rowidx, bg, cf, pr, lens = inputs
     A, C = rowidx.shape
     Bg = u_tab.shape[2]
@@ -1891,10 +1836,6 @@ def _run_chunk_coupled(plan: SweepPlan, active: np.ndarray, inputs,
         return _run_chunk_coupled_sharded(
             plan, inputs, state_slices, scalars, gid, cap_g, office,
             Gd, n_dev)
-    if pallas in ("on", "interpret"):
-        return _run_chunk_coupled_pallas(
-            plan, inputs, state_slices, scalars, gid, cap_g, office,
-            Gd, interpret=(pallas == "interpret"))
 
     Ap = _pad_pow2(A)
     if Ap != A:
@@ -1928,14 +1869,11 @@ def _run_chunk_coupled(plan: SweepPlan, active: np.ndarray, inputs,
     _STATS.devices_used = max(_STATS.devices_used, 1)
     with enable_x64():
         out = _scan_chunk_jax_coupled(
-            jnp.asarray(u_tab, cdt), jnp.asarray(b_tab, cdt),
-            jnp.asarray(rowidx), jnp.asarray(bg, cdt),
-            jnp.asarray(cf, cdt), jnp.asarray(pr, cdt),
-            jnp.asarray(lens, cdt),
-            jnp.asarray(gid), jnp.asarray(cap_g, cdt),
-            jnp.asarray(office, cdt),
-            *(jnp.asarray(a, adt) for a in state_slices),
-            *(jnp.asarray(a, cdt) for a in scalars), B=Bg, G=Gp)
+            *_upload((u_tab, cdt), (b_tab, cdt), (rowidx, None), (bg, cdt),
+                     (cf, cdt), (pr, cdt), (lens, cdt), (gid, None),
+                     (cap_g, cdt), (office, cdt)),
+            *_upload(*((a, adt) for a in state_slices)),
+            *_upload(*((a, cdt) for a in scalars)), B=Bg, G=Gp)
     out = tuple(np.asarray(o) for o in out)
     if Ap != A:
         out = tuple(o[:A] for o in out)
@@ -2010,30 +1948,18 @@ def _run_chunk_coupled_sharded(plan: SweepPlan, inputs, state_slices,
     _STATS.slot_work += n_dev * Ld * C
     _STATS.devices_used = max(_STATS.devices_used, n_dev)
     with enable_x64():
-        out = _sharded_coupled(n_dev, Bg, Gp)(
-            jnp.asarray(stack_lane(u_tab), cdt),
-            jnp.asarray(stack_lane(b_tab, 1.0), cdt),
-            jnp.asarray(stack_lane(rowidx)),
-            jnp.asarray(stack_lane(bg), cdt),
-            jnp.asarray(stack_lane(cf), cdt),
-            jnp.asarray(stack_lane(pr), cdt),
-            jnp.asarray(stack_lane(lens, 3600.0 / plan.sph), cdt),
-            jnp.asarray(gid_s), jnp.asarray(cap_s, cdt),
-            jnp.asarray(off_s, cdt),
-            jnp.asarray(stack_lane(remaining), adt),
-            jnp.asarray(stack_lane(rt), adt),
-            jnp.asarray(stack_lane(kwh), adt),
-            jnp.asarray(stack_lane(co2), adt),
-            jnp.asarray(stack_lane(cost), adt),
-            jnp.asarray(stack_lane(speak), adt),
-            jnp.asarray(stack_lane(n_scen, 1.0), cdt),
-            jnp.asarray(stack_lane(rate), cdt),
-            jnp.asarray(stack_lane(oh), cdt),
-            jnp.asarray(stack_lane(idle), cdt),
-            jnp.asarray(stack_lane(dyn), cdt),
-            jnp.asarray(stack_lane(alpha, 1.0), cdt),
-            jnp.asarray(stack_lane(gamma), cdt),
-            jnp.asarray(stack_lane(ohfrac), cdt))
+        out = _sharded_coupled(n_dev, Bg, Gp)(*_upload(
+            (stack_lane(u_tab), cdt), (stack_lane(b_tab, 1.0), cdt),
+            (stack_lane(rowidx), None), (stack_lane(bg), cdt),
+            (stack_lane(cf), cdt), (stack_lane(pr), cdt),
+            (stack_lane(lens, 3600.0 / plan.sph), cdt),
+            (gid_s, None), (cap_s, cdt), (off_s, cdt),
+            *((stack_lane(a), adt)
+              for a in (remaining, rt, kwh, co2, cost, speak)),
+            (stack_lane(n_scen, 1.0), cdt), (stack_lane(rate), cdt),
+            (stack_lane(oh), cdt), (stack_lane(idle), cdt),
+            (stack_lane(dyn), cdt), (stack_lane(alpha, 1.0), cdt),
+            (stack_lane(gamma), cdt), (stack_lane(ohfrac), cdt)))
     final = []
     for o in out:
         o = np.asarray(o)
@@ -2041,74 +1967,6 @@ def _run_chunk_coupled_sharded(plan: SweepPlan, inputs, state_slices,
             [o[d * Ld:d * Ld + (lane_hi[d] - lane_lo[d])]
              for d in range(n_dev)]))
     return tuple(final)
-
-
-def _run_chunk_coupled_pallas(plan: SweepPlan, inputs, state_slices,
-                              scalars, gid: np.ndarray, cap_g: np.ndarray,
-                              office: np.ndarray, Gd: int,
-                              interpret: bool) -> tuple:
-    """Coupled chunk through the Pallas kernel: lanes are repacked into
-    a dense (group, lane-in-group) layout with the per-slot decision
-    rows pre-gathered, the kernel runs one program per group with the
-    slot loop inside, and results scatter back to flat lane order.
-    Parity with the jnp kernel is pinned to <1e-9 by tests."""
-    from repro.kernels.coupled_throttle import coupled_chunk
-    u_tab, b_tab, rowidx, bg, cf, pr, lens = inputs
-    A, C = rowidx.shape
-    Bg = u_tab.shape[2]
-    E = cf.shape[1]
-    cnt = np.bincount(gid, minlength=Gd)
-    csum = np.concatenate([[0], np.cumsum(cnt)])
-    pos = np.arange(A) - csum[gid]        # position within own group
-    Lp = _pad_pow2(int(cnt.max()))
-    Gp = _pad_pow2(Gd, minimum=1)
-
-    def dense(a, fill=0.0):
-        out = np.full((Gp, Lp) + a.shape[1:], fill, dtype=a.dtype)
-        out[gid, pos] = a
-        return out
-
-    # hoist the per-lane dynamic row gather out of the kernel
-    u_rows = np.take_along_axis(u_tab, rowidx[:, :, None], axis=1)
-    b_rows = np.take_along_axis(b_tab, rowidx[:, :, None], axis=1)
-    cap_p = np.pad(cap_g, (0, Gp - Gd), constant_values=np.inf)
-    off_p = np.pad(office, ((0, Gp - Gd), (0, 0)))
-    remaining, rt, kwh, co2, cost, speak = state_slices
-    n_scen, rate, oh, idle, dyn, alpha, gamma, ohfrac = scalars
-    cdt, adt = _plan_dtypes(plan)
-    sig = ("pallas", Gp, Lp, C, Bg, E, plan.price is not None,
-           plan.precision)
-    _STATS.jit_shapes.add(sig)
-    _STATS.chunks += 1
-    _STATS.slot_work += Gp * Lp * C
-    _STATS.devices_used = max(_STATS.devices_used, 1)
-    _STATS.pallas_dispatches += 1
-    with enable_x64():
-        out = coupled_chunk(
-            jnp.asarray(dense(u_rows), cdt),
-            jnp.asarray(dense(b_rows, 1.0), cdt),
-            jnp.asarray(dense(bg), cdt),
-            jnp.asarray(dense(cf), cdt),
-            jnp.asarray(dense(pr), cdt),
-            jnp.asarray(dense(lens, 3600.0 / plan.sph), cdt),
-            jnp.asarray(cap_p, cdt), jnp.asarray(off_p, cdt),
-            jnp.asarray(dense(remaining), adt),
-            jnp.asarray(dense(rt), adt),
-            jnp.asarray(dense(kwh), adt),
-            jnp.asarray(dense(co2), adt),
-            jnp.asarray(dense(cost), adt),
-            jnp.asarray(dense(speak), adt),
-            jnp.asarray(dense(n_scen, 1.0), cdt),
-            jnp.asarray(dense(rate), cdt),
-            jnp.asarray(dense(oh), cdt),
-            jnp.asarray(dense(idle), cdt),
-            jnp.asarray(dense(dyn), cdt),
-            jnp.asarray(dense(alpha, 1.0), cdt),
-            jnp.asarray(dense(gamma), cdt),
-            jnp.asarray(dense(ohfrac), cdt),
-            iters=model.SITE_THROTTLE_ITERS, finish_frac=_FINISH_FRAC,
-            interpret=interpret)
-    return tuple(np.asarray(o)[gid, pos] for o in out)
 
 
 def _chunk_inputs(plan: SweepPlan, active: np.ndarray, t0: int,
@@ -2191,8 +2049,7 @@ def _stall_diagnostic(plan: SweepPlan, lane: int, remaining: float) -> str:
 def execute_plan(plan: SweepPlan, *, backend: Optional[str] = None,
                  chunk_days: Optional[int] = None,
                  mode: str = "chunked",
-                 devices: Optional[int] = None,
-                 pallas=None) -> _ScanState:
+                 devices: Optional[int] = None) -> _ScanState:
     """Run the scan over a compiled plan and return the final state.
 
     `mode="chunked"` (default) is the resumable scan: fixed-shape chunks
@@ -2207,11 +2064,8 @@ def execute_plan(plan: SweepPlan, *, backend: Optional[str] = None,
     devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
     *before* jax initializes — see core/xla_profiles.py).  Uncoupled
     sweeps shard bitwise-identically; coupled plans split only at group
-    boundaries so the site cap never crosses a shard.  `pallas` picks
-    the coupled-chunk kernel implementation (None → ``CARINA_PALLAS``
-    env, default "auto"; see `_resolve_pallas`); the Pallas path is
-    single-device only and the jnp kernel remains the fallback.  The
-    scan's dtype policy is fixed at `compile_plan(precision=...)` time.
+    boundaries so the site cap never crosses a shard.  The scan's dtype
+    policy is fixed at `compile_plan(precision=...)` time.
 
     Stall detection: provably-dead periodic tables are diagnosed at
     compile time; beyond that, the chunked executor raises the stall
@@ -2228,20 +2082,18 @@ def execute_plan(plan: SweepPlan, *, backend: Optional[str] = None,
     if mode == "monolithic":
         use_jax = _use_jax(backend)
         n_dev = _resolve_devices(devices, use_jax)
-        pallas_mode = _resolve_pallas(pallas, use_jax)
         _STATS.precision_mode = plan.precision if use_jax else "fp64"
-        return _execute_monolithic(plan, use_jax, n_dev, pallas_mode)
+        return _execute_monolithic(plan, use_jax, n_dev)
 
     return execute_interval(plan, backend=backend, chunk_days=chunk_days,
-                            devices=devices, pallas=pallas).state
+                            devices=devices).state
 
 
 def execute_interval(plan: SweepPlan, cursor: Optional[PlanCursor] = None, *,
                      until_slot: Optional[int] = None,
                      backend: Optional[str] = None,
                      chunk_days: Optional[int] = None,
-                     devices: Optional[int] = None,
-                     pallas=None) -> PlanCursor:
+                     devices: Optional[int] = None) -> PlanCursor:
     """Advance the chunked scan from `cursor` (a fresh one when None) to
     `until_slot` (to completion when None) and return the new cursor.
 
@@ -2257,7 +2109,6 @@ def execute_interval(plan: SweepPlan, cursor: Optional[PlanCursor] = None, *,
         raise ValueError(f"chunk_days must be >= 1, got {chunk_days}")
     use_jax = _use_jax(backend)
     n_dev = _resolve_devices(devices, use_jax)
-    pallas_mode = _resolve_pallas(pallas, use_jax)
     _STATS.precision_mode = plan.precision if use_jax else "fp64"
     H = 24 * plan.sph
     max_slots = plan.max_slots
@@ -2285,8 +2136,7 @@ def execute_interval(plan: SweepPlan, cursor: Optional[PlanCursor] = None, *,
         if coupled:
             state = state + (speak[active],)
         before = remaining[active].copy()
-        out = _run_chunk(plan, active, inputs, state, use_jax, n_dev,
-                         pallas_mode)
+        out = _run_chunk(plan, active, inputs, state, use_jax, n_dev)
         if coupled:
             speak[active] = out[5]
         remaining[active], rt[active], kwh[active], co2[active], \
@@ -2318,8 +2168,8 @@ def execute_interval(plan: SweepPlan, cursor: Optional[PlanCursor] = None, *,
                       t0=t0, active=active)
 
 
-def _execute_monolithic(plan: SweepPlan, use_jax: bool, n_dev: int = 1,
-                        pallas: str = "off") -> _ScanState:
+def _execute_monolithic(plan: SweepPlan, use_jax: bool,
+                        n_dev: int = 1) -> _ScanState:
     """The pre-chunking behaviour: scan everything from t=0 over one
     estimated horizon, double and re-scan on undershoot."""
     H = 24 * plan.sph
@@ -2333,8 +2183,7 @@ def _execute_monolithic(plan: SweepPlan, use_jax: bool, n_dev: int = 1,
                  np.zeros((L, plan.E)), np.zeros(L))
         if plan.coupled:
             state = state + (np.zeros(L),)
-        out = _run_chunk(plan, all_lanes, inputs, state, use_jax, n_dev,
-                         pallas)
+        out = _run_chunk(plan, all_lanes, inputs, state, use_jax, n_dev)
         remaining = out[0]
         if (remaining <= _FINISH_FRAC * plan.n_scen).all():
             return _ScanState(*out)
@@ -2487,6 +2336,8 @@ class TraceObjective:
         self.has_price = price is not None
         self.use_jax = _use_jax(backend)
         self._jit = None
+        if self.use_jax:
+            enable_persistent_compilation_cache()
 
         wl, mach = case.workload, case.machine
         self._scalars = (float(wl.n_scenarios), float(wl.rate_at_full),
@@ -2741,6 +2592,8 @@ class FleetTraceObjective:
         self.has_price = price is not None
         self.use_jax = _use_jax(backend)
         self._jit = None
+        if self.use_jax:
+            enable_persistent_compilation_cache()
 
         case0 = cases[0]
         self._scalars = tuple(
@@ -2909,14 +2762,10 @@ class FleetTraceObjective:
 
 
 def _use_jax(backend: Optional[str]) -> bool:
-    if backend == "numpy":
-        return False
-    if backend == "jax":
-        if not _HAS_JAX:
-            raise RuntimeError("backend='jax' requested but jax is not "
-                               "importable")
-        return True
-    return _HAS_JAX
+    if backend not in (None, "jax", "numpy"):
+        raise ValueError(f"backend must be 'jax' or 'numpy', got "
+                         f"{backend!r}")
+    return backend != "numpy"
 
 
 def trace_sweep(cases: Sequence, price: Optional[Signal] = None, *,
@@ -2929,7 +2778,6 @@ def trace_sweep(cases: Sequence, price: Optional[Signal] = None, *,
                 group_office_kw: Optional[Sequence[float]] = None,
                 precision: str = "fp64",
                 devices: Optional[int] = None,
-                pallas=None,
                 cache_dir: Optional[str] = None) -> List[SimResult]:
     """Evaluate cases on the trace grid; order is preserved.
 
@@ -2955,8 +2803,8 @@ def trace_sweep(cases: Sequence, price: Optional[Signal] = None, *,
     returns per-group site rollups.
 
     Scale-out knobs: `precision` is the plan dtype policy (see
-    `compile_plan`), `devices` the `shard_map` lane fan-out and
-    `pallas` the coupled-kernel dispatch policy (see `execute_plan`).
+    `compile_plan`) and `devices` the `shard_map` lane fan-out (see
+    `execute_plan`).
     `cache_dir` points compilation at a persistent on-disk plan cache
     (default: the `CARINA_PLAN_CACHE` env var; see `core.plancache`).
     """
@@ -2968,5 +2816,5 @@ def trace_sweep(cases: Sequence, price: Optional[Signal] = None, *,
                         group_office_kw=group_office_kw,
                         precision=precision, cache_dir=cache_dir)
     state = execute_plan(plan, backend=backend, chunk_days=chunk_days,
-                         mode=mode, devices=devices, pallas=pallas)
+                         mode=mode, devices=devices)
     return summarize_plan(plan, state)

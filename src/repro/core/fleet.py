@@ -143,7 +143,6 @@ def fleet_sweep(fleet_cases: Sequence[Sequence[SweepCase]],
                 chunk_days: Optional[int] = None,
                 precision: str = "fp64",
                 devices: Optional[int] = None,
-                pallas=None,
                 cache_dir: Optional[str] = None) -> List[FleetResult]:
     """Evaluate fleet cases (each a group of M member `SweepCase`s) on
     the grouped-lane trace engine; order is preserved.
@@ -153,9 +152,9 @@ def fleet_sweep(fleet_cases: Sequence[Sequence[SweepCase]],
     keep the cheap 24-slot path, and results are bitwise-identical to
     sweeping the members independently).
 
-    `precision`/`devices`/`pallas` are the engine's scale-out knobs
-    (dtype policy, shard_map lane fan-out, coupled-kernel dispatch —
-    see `engine_jax.compile_plan` and `execute_plan`); coupled sweeps
+    `precision`/`devices` are the engine's scale-out knobs (dtype
+    policy and shard_map lane fan-out — see `engine_jax.compile_plan`
+    and `execute_plan`); coupled sweeps
     shard at group boundaries so the site cap stays device-local.
     `cache_dir` points plan compilation at a persistent on-disk cache
     (default: the `CARINA_PLAN_CACHE` env var; see `core.plancache`).
@@ -193,7 +192,7 @@ def fleet_sweep(fleet_cases: Sequence[Sequence[SweepCase]],
                         group_office_kw=[site.office_kw] * G,
                         precision=precision, cache_dir=cache_dir)
     state = execute_plan(plan, backend=backend, chunk_days=chunk_days,
-                         devices=devices, pallas=pallas)
+                         devices=devices)
     res = summarize_plan(plan, state)
     out = []
     i = 0
@@ -437,8 +436,7 @@ class Fleet:
               backend: Optional[str] = None,
               max_days: int = 240,
               precision: str = "fp64",
-              devices: Optional[int] = None,
-              pallas=None) -> List[FleetResult]:
+              devices: Optional[int] = None) -> List[FleetResult]:
         """Evaluate fleet assignments jointly under the site.
 
         Each assignment is an `AllocationSchedule`, a single schedule
@@ -493,7 +491,7 @@ class Fleet:
         out = fleet_sweep(groups, self.site, price=self.site.price,
                           names=labels, backend=backend, max_days=max_days,
                           precision=precision, devices=devices,
-                          pallas=pallas, cache_dir=self.cache_dir)
+                          cache_dir=self.cache_dir)
         if deltas:
             for fr in out:
                 for c, r in zip(self.campaigns, fr.campaigns):

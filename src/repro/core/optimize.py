@@ -15,8 +15,8 @@ module answers it by treating the trace-grid engine as an objective:
   * two search modes share one scalarization: **grad** (Adam through the
     scan — exact gradients of energy/CO2/runtime w.r.t. every slot) for
     the smooth family, and **cem** (a vmapped cross-entropy population
-    search, hundreds of candidates per jit call, NumPy fallback when JAX
-    is absent) which needs no gradients and handles quantized/discrete
+    search, hundreds of candidates per jit call, or the NumPy backend
+    on request) which needs no gradients and handles quantized/discrete
     intensity levels.
 
 Objectives are weighted sums over campaign metrics plus ε-constraints
@@ -29,8 +29,8 @@ runtime/energy (or runtime/CO2) trade curve in one search — the same
 `SimResult` rows the frontier dashboards already render.
 
 The session-level entry point is `Campaign.optimize(...)`
-(`core/session.py`); this module is the engine room and is importable
-without JAX (method="cem" runs on the NumPy backend).
+(`core/session.py`); this module is the engine room (method="cem" also
+runs on the NumPy backend).
 """
 from __future__ import annotations
 
@@ -378,7 +378,7 @@ def optimize_schedule(case, objective: Union[str, Mapping, Objective] = "co2",
     from a cold one), ``"cem"`` (vmapped population search; robust, runs
     on the NumPy backend too), ``"cem+grad"`` (population search, then
     gradient polish from its best candidate), or ``"auto"``
-    (cem+grad when JAX is importable, else cem).  `init` seeds the
+    (cem+grad on the JAX backend without `levels`, else cem).  `init` seeds the
     search — a flat intensity or
     a per-slot table (e.g. an existing policy's, via
     `ParametricSchedule.from_intensities`).  `levels`, if given,

@@ -571,7 +571,6 @@ def execute_assignment(assignment: Assignment, window: ServingWindow,
                        backend: Optional[str] = None,
                        precision: str = "fp64",
                        devices: Optional[int] = None,
-                       pallas=None,
                        cache_dir: Optional[str] = None
                        ) -> Tuple[List[SimResult], AllocationSchedule,
                                   Optional[float]]:
@@ -580,8 +579,8 @@ def execute_assignment(assignment: Assignment, window: ServingWindow,
     compiled sweep for the whole window.  Returns the per-lane
     `SimResult`s (empty tiers skipped), the executed
     `AllocationSchedule` demand block, and the peak site draw (kW,
-    site-coupled runs only).  `precision`/`devices`/`pallas` forward to
-    the engine's scale-out knobs (see `engine_jax.execute_plan`)."""
+    site-coupled runs only).  `precision`/`devices` forward to the
+    engine's scale-out knobs (see `engine_jax.execute_plan`)."""
     day = 24 * window.sph
     day_idx = _day_slot_index(window)
     trace = _window_trace(window)
@@ -625,8 +624,7 @@ def execute_assignment(assignment: Assignment, window: ServingWindow,
     plan = compile_plan(cases, price=window.price,
                         slots_per_hour=window.sph, precision=precision,
                         cache_dir=cache_dir, **groups)
-    state = execute_plan(plan, backend=backend, devices=devices,
-                         pallas=pallas)
+    state = execute_plan(plan, backend=backend, devices=devices)
     results = summarize_plan(plan, state)
     peak = (float(np.max(state.site_kw_peak))
             if state.site_kw_peak is not None else None)

@@ -157,7 +157,7 @@ class Campaign:
         (throughput, power) targets, and fits `core/model.py`'s
         parameters starting from this campaign's configured values —
         Adam through the differentiable model (`core/calibrate.py`;
-        `backend="numpy"` forces the finite-difference fallback).
+        `backend="numpy"` runs the finite-difference mirror instead).
         `bootstrap` > 0 adds seeded unit-resampling confidence
         intervals.  Returns a `CalibratedModel`; with `apply=True` the
         fitted (workload, machine) replace this campaign's calibrated

@@ -37,15 +37,10 @@ def detect_host() -> Dict:
         "cpus": os.cpu_count() or 1,
         "mem_gb": _read_meminfo_gb(),
     }
-    try:
-        import jax
-        info["jax_backend"] = jax.default_backend()
-        info["jax_devices"] = len(jax.devices())
-        info["jax_device_kind"] = jax.devices()[0].device_kind
-    except Exception:
-        info["jax_backend"] = None
-        info["jax_devices"] = 0
-        info["jax_device_kind"] = "unknown"
+    import jax
+    info["jax_backend"] = jax.default_backend()
+    info["jax_devices"] = len(jax.devices())
+    info["jax_device_kind"] = jax.devices()[0].device_kind
     return info
 
 
@@ -63,20 +58,27 @@ def machine_profile_from_host(info: Optional[Dict] = None) -> MachineProfile:
                                idle_w=idle, dyn_w=dyn)
 
 
-# Known accelerator energy profiles (per-chip; estimation constants)
+# Known accelerator energy profiles (per chip), keyed by the exact
+# `device_kind` jax reports.  Peak bf16 FLOP/s and HBM bandwidth are the
+# published per-chip figures of Google Cloud's TPU documentation ("TPU
+# v5e", "TPU v5p", "TPU v4" system-architecture pages); idle/TDP watts
+# and ICI bandwidth are estimation constants (see energy.ChipProfile).
 _CHIP_TABLE = {
-    "tpu v5e": ChipProfile(),
-    "tpu v5": ChipProfile(name="tpu-v5p", peak_flops=459e12, hbm_bw=2765e9,
+    "TPU v5 lite": ChipProfile(),                 # v5e: 197 TFLOP/s, 819 GB/s
+    "TPU v5": ChipProfile(name="tpu-v5p", peak_flops=459e12, hbm_bw=2765e9,
                           ici_bw=90e9, idle_w=90.0, tdp_w=350.0),
-    "tpu v4": ChipProfile(name="tpu-v4", peak_flops=275e12, hbm_bw=1228e9,
+    "TPU v4": ChipProfile(name="tpu-v4", peak_flops=275e12, hbm_bw=1228e9,
                           ici_bw=50e9, idle_w=90.0, tdp_w=300.0),
 }
 
 
 def chip_profile_from_host(info: Optional[Dict] = None) -> ChipProfile:
+    """The energy profile of the host's accelerator, by exact device
+    kind; a kind not in the table (a CPU among them) is an error."""
     info = info or detect_host()
-    kind = (info.get("jax_device_kind") or "").lower()
-    for key, prof in _CHIP_TABLE.items():
-        if key in kind:
-            return prof
-    return ChipProfile()  # v5e-class default (the assignment target)
+    kind = info.get("jax_device_kind")
+    if kind not in _CHIP_TABLE:
+        raise ValueError(
+            f"no chip profile for device kind {kind!r}; known kinds: "
+            f"{sorted(_CHIP_TABLE)}")
+    return _CHIP_TABLE[kind]

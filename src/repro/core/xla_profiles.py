@@ -4,8 +4,10 @@ XLA reads its flags from the ``XLA_FLAGS`` environment variable once, at
 backend initialization — flags changed after the first `jax.devices()`
 call are silently ignored.  This module therefore deals only in
 *strings and environment dicts* (no jax import at module scope) so that
-benchmark parents and test harnesses can assemble an environment for a
-subprocess, and applications can call `apply_profile` before first use.
+test harnesses can assemble an environment for a CPU subprocess, and
+applications can call `apply_profile` before first use.  `fanout_env`
+is for CPU tests only: on an accelerator host a child of a process that
+holds the chip cannot reach it, so multi-chip runs stay in one process.
 
 The flag-dictionary pattern (one dict per profile, merged and rendered
 as ``--name=value`` tokens) mirrors how production jax codebases ship
@@ -33,8 +35,8 @@ from typing import Dict, Mapping, Optional
 
 # One dict per profile; values are strings exactly as XLA parses them.
 CPU_SCAN_FLAGS: Dict[str, str] = {
-    # Parity pins (bitwise fp64 shard-vs-single, 1e-9 Pallas-vs-jnp)
-    # assume IEEE semantics; never trade them for fast-math.
+    # Parity pins (bitwise fp64 shard-vs-single) assume IEEE
+    # semantics; never trade them for fast-math.
     "xla_cpu_enable_fast_math": "false",
     # The chunk kernels are large fused loops; multi-threaded Eigen
     # helps the single-device path on multi-core hosts.
@@ -81,9 +83,10 @@ def fanout_env(devices: int, profile: str = "cpu_scan", *,
                extra: Optional[Mapping[str, str]] = None,
                base_env: Optional[Mapping[str, str]] = None
                ) -> Dict[str, str]:
-    """A full environment dict for launching a subprocess with `devices`
-    virtual CPU devices under `profile`.  Pins ``JAX_PLATFORMS=cpu`` so
-    the fan-out flag is honored even where other backends exist."""
+    """A full environment dict for launching a CPU test subprocess with
+    `devices` virtual CPU devices under `profile`.  Pins
+    ``JAX_PLATFORMS=cpu`` so the fan-out flag is honored even where other
+    backends exist (and so the child never competes for a chip)."""
     env = dict(base_env if base_env is not None else os.environ)
     merged = dict(fanout_flags(devices))
     if extra:

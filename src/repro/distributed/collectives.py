@@ -22,8 +22,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 F32 = jnp.float32
 INT8_MAX = 127.0
 
@@ -42,7 +40,7 @@ def int8_ring_allreduce(x: jax.Array, axis: str) -> jax.Array:
     """Inside shard_map: sum `x` (any shape, fp32) over `axis` with int8 wire
     traffic.  Chunked ring: reduce-scatter (n-1 hops) + all-gather (n-1 hops);
     every hop sends one int8 chunk + fp32 scale."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis)
@@ -91,7 +89,7 @@ def _size(shape) -> int:
 def compressed_psum_grads(grads, residuals, axis: str):
     """Inside shard_map: mean-all-reduce `grads` over `axis` in int8 with
     sender-side error feedback.  Returns (reduced grads, new residuals)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g, r):
         gf = g.astype(F32) + r
@@ -122,7 +120,7 @@ def allgather_matmul_overlapped(x: jax.Array, w_shard: jax.Array, axis: str):
     w_shard: (k/n, f).  Each step multiplies the chunk currently held while
     the next chunk is in flight.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     k_shard = w_shard.shape[0]
     left = [(j, (j - 1) % n) for j in range(n)]

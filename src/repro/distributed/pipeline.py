@@ -25,10 +25,8 @@ import functools
 from typing import Any, Callable
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:
@@ -70,14 +68,13 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stage_params: Any,
         # only the last stage's buffer is meaningful; broadcast it to every
         # stage via a masked psum so the caller sees a replicated result
         outs = jnp.where(idx == n_stages - 1, outs, jnp.zeros_like(outs))
-        outs = jax.lax.psum(outs, axis)
-        return outs[None]
+        return jax.lax.psum(outs, axis)
 
+    # Auto axes: the replicated result then carries no mesh type, so
+    # callers may differentiate it outside a mesh context
+    auto = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
-    out_specs = P(axis)
-    y = shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)(
-        jax.tree.map(lambda t: t, stage_params), micro)
-    # out dim0 = n_stages (one copy per stage); take the replicated copy
-    y = y[0] if n_stages == 1 else y[0]
+    y = jax.shard_map(per_stage, mesh=auto, in_specs=in_specs,
+                  out_specs=P(), check_vma=False)(stage_params, micro)
     return y.reshape((b,) + x.shape[1:])

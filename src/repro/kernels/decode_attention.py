@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 F32 = jnp.float32
 NEG_INF = -1e30
 LANES = 128
@@ -126,7 +124,7 @@ def decode_attention(q, k, v, length, *, nsplit: int = 8, block_k: int = 256,
             pltpu.VMEM((g, LANES), F32),
             pltpu.VMEM((g, LANES), F32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(length, qg, k, v)

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 F32 = jnp.float32
 NEG_INF = -1e30
 LANES = 128
@@ -134,7 +132,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, LANES), F32),
             pltpu.VMEM((bq, LANES), F32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 F32 = jnp.float32
 
 
@@ -74,7 +72,7 @@ def grouped_gemm(x, w, block_ids, *, block_m: int = 128, block_n: int = 128,
             scratch_shapes=[pltpu.VMEM((block_m, bn), F32)],
         ),
         out_shape=jax.ShapeDtypeStruct((t, f_p), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_ids, x, w)

@@ -11,8 +11,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 F32 = jnp.float32
 
 
@@ -42,7 +40,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, block_t: int = 256,
         ],
         out_specs=pl.BlockSpec((bt, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t_p, d), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, scale)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 F32 = jnp.float32
 
 
@@ -79,7 +77,7 @@ def ssm_scan(a, b, *, chunk: int = 128, block_c: int = 512, interpret: bool = Fa
             jax.ShapeDtypeStruct((B, c_p), F32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bc), F32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

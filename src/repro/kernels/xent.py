@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 F32 = jnp.float32
 NEG_INF = -1e30
 LANES = 128
@@ -98,7 +96,7 @@ def blocked_xent(x, emb, labels, *, block_t: int = 256, block_v: int = 2048,
             pltpu.VMEM((bt, LANES), F32),
             pltpu.VMEM((bt, LANES), F32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, emb, labels2)

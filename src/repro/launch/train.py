@@ -5,7 +5,9 @@
 
 On a real TPU fleet this binary runs per host (jax.distributed.initialize);
 here it sizes itself to the local device count.  Selects the Pallas kernel
-path automatically on TPU backends.
+path automatically on TPU backends.  The chip's energy profile comes
+from its device kind (core/sysinfo.py), so it needs a known TPU; for a
+CPU run of the controller-gated loop use examples/quickstart.py.
 """
 from __future__ import annotations
 
@@ -40,6 +42,12 @@ def main():
     ap.add_argument("--remat", default="none", choices=["none", "dots", "full"])
     ap.add_argument("--blocked-xent", action="store_true")
     args = ap.parse_args()
+    # Algorithm 1 line 3: detect machine characteristics.  The chip
+    # profile is looked up by device kind, so a host without a known
+    # accelerator stops here instead of borrowing another chip's numbers.
+    from repro.core.sysinfo import chip_profile_from_host, detect_host
+    host = detect_host()
+    chip = chip_profile_from_host(host)
 
     L.set_kernel_mode("auto")      # pallas on TPU, XLA elsewhere
     cfg = get_config(args.arch, smoke=args.smoke)
@@ -57,9 +65,6 @@ def main():
     opt = AdamWConfig(total_steps=args.steps,
                       warmup_steps=max(1, args.steps // 10))
     data = SyntheticLM(cfg, batch=args.batch, seq=args.seq)
-    # Algorithm 1 line 3: detect machine characteristics, initialize session
-    from repro.core.sysinfo import chip_profile_from_host, detect_host
-    host = detect_host()
     campaign = carina.Campaign(
         carina.TrainingCampaign(f"train-{cfg.name}", cfg.name,
                                 total_steps=args.steps, steps_per_unit=10),
@@ -68,7 +73,7 @@ def main():
     controller = campaign.controller(
         max_replicas=n_dev,
         clock=SimClock(start_hour=9.0, speedup=600.0),
-        chip=chip_profile_from_host(host))
+        chip=chip)
     campaign.tracker.meta["host"] = host
     res = run_training(model, opt, data,
                        LoopConfig(total_steps=args.steps, steps_per_unit=10,

@@ -20,8 +20,6 @@ import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -121,7 +119,7 @@ def make_dp_compressed_step(model: Model, opt_cfg: AdamWConfig, mesh: Mesh,
         in_specs = (jax.tree.map(lambda _: rep, state),
                     jax.tree.map(lambda _: P(dp_axis), batch))
         out_state_spec = jax.tree.map(lambda _: rep, state)
-        fn = shard_map(
+        fn = jax.shard_map(
             local_step, mesh=mesh, in_specs=in_specs,
             out_specs=(out_state_spec,
                        {"nll": rep, "acc": rep, "aux": rep, "lr": rep,

@@ -174,7 +174,6 @@ def test_int8_ring_allreduce_and_compressed_step():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.distributed.collectives import int8_ring_allreduce, \
         allgather_matmul_overlapped
 
@@ -185,7 +184,7 @@ def test_int8_ring_allreduce_and_compressed_step():
         return int8_ring_allreduce(xs[0], 'data')   # same value all shards
 
     # each shard contributes its row; compare vs exact sum
-    y = shard_map(lambda xs: int8_ring_allreduce(xs, 'data')[None],
+    y = jax.shard_map(lambda xs: int8_ring_allreduce(xs, 'data')[None],
                       mesh=mesh, in_specs=P('data', None),
                       out_specs=P('data', None), check_vma=False)(x)
     exact = np.asarray(x).sum(0)
@@ -199,7 +198,7 @@ def test_int8_ring_allreduce_and_compressed_step():
     k, f_ = 64, 32
     xx = jax.random.normal(jax.random.PRNGKey(1), (16, k))
     w = jax.random.normal(jax.random.PRNGKey(2), (k, f_)) * 0.1
-    y2 = shard_map(
+    y2 = jax.shard_map(
         lambda w_s: allgather_matmul_overlapped(xx, w_s, 'data'),
         mesh=mesh, in_specs=P('data', None), out_specs=P(), check_vma=False)(w)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(xx @ w),

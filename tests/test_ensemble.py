@@ -25,7 +25,7 @@ from repro.core import (BASELINE, Campaign, MachineProfile,
                         deadline_schedule, hourly_schedule,
                         progress_ramp_schedule, sweep, trace_sweep,
                         trace_windows)
-from repro.core.engine_jax import (_HAS_JAX, TraceObjective, compile_plan,
+from repro.core.engine_jax import (TraceObjective, compile_plan,
                                    execute_plan, reset_scan_stats,
                                    scan_stats, summarize_plan)
 from repro.core.optimize import (Objective, optimize_schedule,
@@ -98,8 +98,6 @@ def test_chunked_numpy_backend_matches_jax(calibrated):
     cases = [SweepCase(BASELINE, wl, m),
              SweepCase(progress_ramp_schedule(0.4, 0.9), wl, m)]
     np_res = trace_sweep(cases, backend="numpy")
-    if not _HAS_JAX:
-        pytest.skip("jax not importable; numpy fallback already exercised")
     jax_res = trace_sweep(cases, backend="jax")
     for a, b in zip(np_res, jax_res):
         assert abs(b.runtime_h / a.runtime_h - 1) < 1e-12, a.policy
@@ -497,7 +495,6 @@ def test_campaign_optimize_cvar_e32_numpy_backend():
     assert res.result.co2_ensemble.n_members == 32
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="jit path needs jax")
 def test_campaign_optimize_cvar_e32_jit_backend():
     """Acceptance: the same robust search through the jitted scan —
     including gradients through the CVaR sort."""

@@ -141,10 +141,6 @@ def test_grouped_engine_matches_oracle_under_cap(calibrated, backend):
     """Every bundled allocation family, coupled under an active cap:
     the grouped-lane scan agrees with the python per-slot oracle to
     <0.5 % on runtime/energy/CO2, and site peaks to <1 %."""
-    if backend == "jax":
-        from repro.core.engine_jax import _HAS_JAX
-        if not _HAS_JAX:
-            pytest.skip("jax not importable")
     dls = (300.0, 480.0)
     families = [
         proportional_split(0.8).for_fleet(2),

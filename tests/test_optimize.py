@@ -19,7 +19,7 @@ import pytest
 
 from repro.core import (Campaign, MachineProfile, POLICIES, SweepCase,
                         TimeBands, TraceSignal, HourlySignal, trace_sweep)
-from repro.core.engine_jax import _HAS_JAX, TraceObjective, evaluate_params
+from repro.core.engine_jax import TraceObjective, evaluate_params
 from repro.core.optimize import (Objective, canonical_metric,
                                  optimize_schedule, pareto_front)
 from repro.core.schedule import ParametricSchedule, parametric_schedule
@@ -77,7 +77,6 @@ def week_trace():
 # ---------------------------------------------------------------------------
 # Acceptance: analytic optimum, grad vs population, beats the Figure-1 set
 # ---------------------------------------------------------------------------
-@pytest.mark.skipif(not _HAS_JAX, reason="gradient search needs jax")
 def test_grad_recovers_analytic_two_band_optimum(toy):
     case, co2_star = toy
     res = optimize_schedule(case, "co2", {"runtime_h": 24.0}, method="grad",
@@ -127,9 +126,8 @@ def test_optimized_beats_six_policies_oem_case1(week_trace):
     six = c.sweep(list(POLICIES.values()), carbon_trace=week_trace)
     deadline = max(r.runtime_h for r in six)
     best_six = min(r.energy_kwh for r in six)
-    method = "auto" if _HAS_JAX else "cem"
     res = c.optimize("energy", deadline_h=deadline, carbon_trace=week_trace,
-                     method=method, candidates=256, iterations=30, steps=400)
+                     method="auto", candidates=256, iterations=30, steps=400)
     assert res.result.runtime_h <= deadline * 1.005
     assert res.result.energy_kwh <= best_six
     # the optimizer's own metrics agree with the engine's SimResult
@@ -192,7 +190,6 @@ def test_trace_objective_is_engine_consistent(toy):
     assert abs(float(mets.unfinished[0])) < 1e-12
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="needs jax")
 def test_evaluate_params_grad_and_vmap_compatible(toy):
     import jax
     import jax.numpy as jnp

@@ -41,12 +41,10 @@ def calibrated():
 
 @pytest.fixture(autouse=True)
 def _no_env_cache(monkeypatch):
-    """Keep ambient CARINA_PLAN_CACHE* / CARINA_JAX_CACHE out of every
-    test: caching is exercised only through explicit cache_dir=
-    arguments here."""
+    """Keep ambient CARINA_PLAN_CACHE* out of every test: plan caching
+    is exercised only through explicit cache_dir= arguments here."""
     monkeypatch.delenv("CARINA_PLAN_CACHE", raising=False)
     monkeypatch.delenv("CARINA_PLAN_CACHE_MB", raising=False)
-    monkeypatch.delenv("CARINA_JAX_CACHE", raising=False)
 
 
 def _res_key(r):
@@ -145,13 +143,14 @@ def test_fleet_warm_start_across_processes(calibrated, tmp_path):
 
 
 def test_xla_compilation_cache_warm_across_processes(tmp_path):
-    """Satellite: the persistent *XLA* compilation cache rides next to
-    the plan store (`<cache_dir>/xla`, wired by compile_plan through
+    """The persistent *XLA* compilation cache goes where
+    ``JAX_COMPILATION_CACHE_DIR`` says (wired by compile_plan through
     `repro.compat.enable_persistent_compilation_cache`).  The plan
     store skips re-*lowering*; this skips re-*compiling* the jitted
     scan itself.  A fresh process re-running the same sweep must load
     its executable from disk: cold = compilation-cache misses + files
-    written, warm = hits with zero misses, results bitwise."""
+    written, warm = hits with zero misses, results bitwise.  The
+    children stay on the CPU."""
     d = str(tmp_path / "store")
     script = textwrap.dedent("""
         import dataclasses, glob, json, os, sys
@@ -178,7 +177,7 @@ def test_xla_compilation_cache_warm_across_processes(tmp_path):
         res = trace_sweep([SweepCase(constant_schedule(0.8), wl, m,
                                      carbon=trace)],
                           cache_dir=sys.argv[1])
-        xla = os.path.join(sys.argv[1], "xla")
+        xla = os.environ["JAX_COMPILATION_CACHE_DIR"]
         files = [p for p in glob.glob(os.path.join(xla, "**", "*"),
                                       recursive=True) if os.path.isfile(p)]
         print(json.dumps({"misses": counts["misses"],
@@ -186,9 +185,9 @@ def test_xla_compilation_cache_warm_across_processes(tmp_path):
                           "co2": res[0].co2_kg}))
     """)
     env = dict(os.environ,
-               PYTHONPATH=os.path.join(ROOT, "src"), JAX_PLATFORMS="cpu")
-    for k in ("CARINA_PLAN_CACHE", "CARINA_JAX_CACHE"):
-        env.pop(k, None)
+               PYTHONPATH=os.path.join(ROOT, "src"), JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"))
+    env.pop("CARINA_PLAN_CACHE", None)
     runs = []
     for _ in range(2):
         out = subprocess.run([sys.executable, "-c", script, d], env=env,
@@ -203,23 +202,29 @@ def test_xla_compilation_cache_warm_across_processes(tmp_path):
     assert warm["co2"] == cold["co2"]
 
 
-def test_env_var_jax_cache_override(tmp_path, monkeypatch):
-    """CARINA_JAX_CACHE redirects the XLA cache independently of the
-    plan store (compat-level guard, idempotent, soft-fail)."""
+@pytest.mark.parametrize("env_set", [True, False],
+                         ids=["env", "default"])
+def test_env_var_jax_cache_override(calibrated, tmp_path, monkeypatch,
+                                    env_set):
+    """After a sweep, jax's persistent compilation cache sits where
+    ``JAX_COMPILATION_CACHE_DIR`` says; without it, at the fixed path
+    inside the checkout, which git ignores."""
     import jax
 
     from repro import compat
-    override = str(tmp_path / "elsewhere")
-    monkeypatch.setenv("CARINA_JAX_CACHE", override)
-    monkeypatch.setattr(compat, "_compilation_cache_dir", None)
+    if env_set:
+        want = str(tmp_path / "elsewhere")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(ROOT, ".jax_cache")
+        assert compat.DEFAULT_COMPILATION_CACHE_DIR == want
+        with open(os.path.join(ROOT, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
     before = jax.config.jax_compilation_cache_dir
     try:
-        active = compat.enable_persistent_compilation_cache(
-            str(tmp_path / "ignored"))
-        assert active == os.path.abspath(override)
-        # idempotent: a second call with any argument keeps the active dir
-        assert compat.enable_persistent_compilation_cache(None) == \
-            os.path.abspath(override)
+        ej.trace_sweep(_cases(calibrated, 1), backend="numpy")
+        assert jax.config.jax_compilation_cache_dir == want
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
 

@@ -1,14 +1,13 @@
 """Scale-out engine tests: device-sharded execute_plan, per-plan
-precision policy, Pallas coupled-throttle kernel, XLA flag profiles,
-and the reentrant `enable_x64` compat shim.
+precision policy, XLA flag profiles, and the reentrant `enable_x64`
+context manager.
 
 Multi-device cases run in one amortized subprocess (the virtual CPU
 device count is an XLA_FLAGS setting locked at first jax init); the
 subprocess pins sharded-vs-single results bitwise (fp64) and to the
 documented 1e-6 tolerance (mixed), including a coupled fleet sweep.
-Everything else — Pallas interpret-mode parity <1e-9 against the jnp
-coupled kernel on the fleet-oracle scenario, precision accuracy bounds,
-scan_stats counters, fallback rules — runs in-process on one device.
+Everything else — precision accuracy bounds, scan_stats counters —
+runs in-process on one device.
 """
 import dataclasses
 import json
@@ -23,14 +22,11 @@ import pytest
 from repro.core import (BASELINE, GridCarbonModel, MachineProfile,
                         PEAK_AWARE_BOOSTED, Site, SweepCase,
                         calibrate_workload, constant_schedule)
-from repro.core.engine_jax import (_HAS_JAX, _group_cuts, _pad_lanes,
-                                   _pad_pow2, compile_plan, execute_plan,
+from repro.core.engine_jax import (_group_cuts, _pad_lanes, _pad_pow2,
+                                   compile_plan, execute_plan,
                                    reset_scan_stats, scan_stats,
                                    summarize_plan)
-from repro.core.fleet import fleet_sweep, simulate_fleet
 from repro.core.workload import OEM_CASE_1, OEM_CASE_2
-
-pytestmark = pytest.mark.skipif(not _HAS_JAX, reason="jax not installed")
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 SITE = Site(power_cap_kw=0.40, office_kw=0.12)
@@ -176,7 +172,26 @@ def test_fp64_default_reports_precision_mode(calibrated):
     st = scan_stats()
     assert st.precision_mode == "fp64"
     assert st.devices_used == 1
-    assert st.pallas_dispatches == 0
+
+
+@pytest.mark.parametrize("coupled", [False, True],
+                         ids=["uncoupled", "coupled"])
+def test_bytes_uploaded_counts_device_launches(calibrated, coupled):
+    """`bytes_uploaded` counts the host -> device bytes of chunk
+    launches: none on the NumPy backend, and fewer under the mixed
+    policy, whose per-slot inputs are fp32."""
+    def run(precision, backend):
+        plan = (_coupled_plan(calibrated, precision=precision) if coupled
+                else compile_plan(_uncoupled_cases(calibrated, 2),
+                                  precision=precision))
+        reset_scan_stats()
+        execute_plan(plan, backend=backend, devices=1)
+        return scan_stats()
+
+    assert run("fp64", "numpy").bytes_uploaded == 0
+    fp64, mixed = run("fp64", "jax"), run("mixed", "jax")
+    assert fp64.bytes_uploaded > 0 and fp64.chunks == mixed.chunks
+    assert mixed.bytes_uploaded < fp64.bytes_uploaded
 
 
 def test_coupled_mixed_precision_tolerance(calibrated):
@@ -190,65 +205,7 @@ def test_coupled_mixed_precision_tolerance(calibrated):
 
 
 # ---------------------------------------------------------------------------
-# Pallas coupled-throttle kernel (interpret mode on CPU)
-# ---------------------------------------------------------------------------
-def test_pallas_matches_jnp_coupled_kernel(calibrated):
-    """pallas="interpret" reproduces the jnp coupled kernel to <1e-9 on
-    the fleet-oracle scenario (active shared cap, grouped lanes),
-    including runtimes, and bumps the dispatch counter."""
-    plan = _coupled_plan(calibrated)
-    ref = summarize_plan(plan, execute_plan(plan, devices=1))
-    reset_scan_stats()
-    # Pallas covers the single-device coupled path only (with devices>1
-    # the group-sharded jnp kernel wins) — pin devices=1
-    got = summarize_plan(plan, execute_plan(plan, devices=1,
-                                            pallas="interpret"))
-    assert scan_stats().pallas_dispatches > 0
-    for a, b in zip(ref, got):
-        assert abs(a.energy_kwh - b.energy_kwh) <= 1e-9 * abs(a.energy_kwh)
-        assert abs(a.co2_kg - b.co2_kg) <= 1e-9 * abs(a.co2_kg)
-        assert abs(a.runtime_h - b.runtime_h) <= 1e-9 * abs(a.runtime_h)
-
-
-def test_pallas_fleet_sweep_matches_oracle(calibrated):
-    """End-to-end: `fleet_sweep(pallas="interpret")` agrees with the
-    python per-slot oracle to <0.5% under an active cap — the same bar
-    the jnp kernel is held to — and site peaks match the jnp path."""
-    wl1, wl2, m = calibrated
-    cases = [SweepCase(s, w, m, SITE.bands, GridCarbonModel(), 9.0)
-             for s, w in zip((BASELINE, PEAK_AWARE_BOOSTED), (wl1, wl2))]
-    jnp_res = fleet_sweep([cases], SITE, devices=1)[0]
-    pal_res = fleet_sweep([cases], SITE, devices=1,
-                          pallas="interpret")[0]
-    orc = simulate_fleet(cases, SITE)
-    for a, b in zip(pal_res.campaigns, orc.campaigns):
-        assert abs(a.runtime_h / b.runtime_h - 1) < 5e-3
-        assert abs(a.energy_kwh / b.energy_kwh - 1) < 5e-3
-        assert abs(a.co2_kg / b.co2_kg - 1) < 5e-3
-    assert abs(pal_res.site.peak_kw - jnp_res.site.peak_kw) < 1e-9
-
-
-def test_pallas_policy_fallback(calibrated, monkeypatch):
-    """Fallback rules: unavailable Pallas silently degrades to the jnp
-    kernel; an unknown policy string raises; the uncoupled path never
-    dispatches Pallas (the kernel only covers the coupled chunk)."""
-    import repro.core.engine_jax as ej
-    plan = _coupled_plan(calibrated)
-    monkeypatch.setattr(ej, "_pallas_available", lambda: False)
-    reset_scan_stats()
-    execute_plan(plan, devices=1, pallas=True)   # degrades, must not raise
-    assert scan_stats().pallas_dispatches == 0
-    monkeypatch.undo()
-    with pytest.raises(ValueError):
-        execute_plan(plan, devices=1, pallas="bogus")
-    up = compile_plan(_uncoupled_cases(calibrated, 2))
-    reset_scan_stats()
-    execute_plan(up, devices=1, pallas="interpret")
-    assert scan_stats().pallas_dispatches == 0
-
-
-# ---------------------------------------------------------------------------
-# enable_x64 reentrancy (the compat-shim regression)
+# enable_x64 reentrancy
 # ---------------------------------------------------------------------------
 def test_enable_x64_nested_contexts_restore_correctly():
     import jax

@@ -3,6 +3,8 @@ verification (resume/merge/verify)."""
 import json
 import os
 
+import pytest
+
 from repro.core import GridCarbonModel, RunTracker
 from repro.core.sysinfo import (chip_profile_from_host, detect_host,
                                 machine_profile_from_host)
@@ -22,10 +24,18 @@ def test_machine_profile_autodetect():
 
 
 def test_chip_profile_autodetect_defaults_v5e():
-    c = chip_profile_from_host({"jax_device_kind": "cpu"})
-    assert c.name == "tpu-v5e"
-    c2 = chip_profile_from_host({"jax_device_kind": "TPU v4"})
-    assert c2.name == "tpu-v4"
+    """Profiles are looked up by the exact device kind jax reports: a
+    v5e reports "TPU v5 lite" (which must not match v5p's "TPU v5"),
+    and a kind not in the table is an error, never a default."""
+    c = chip_profile_from_host({"jax_device_kind": "TPU v5 lite"})
+    assert c.name == "tpu-v5e" and c.peak_flops == 197e12
+    assert chip_profile_from_host(
+        {"jax_device_kind": "TPU v5"}).name == "tpu-v5p"
+    assert chip_profile_from_host(
+        {"jax_device_kind": "TPU v4"}).name == "tpu-v4"
+    for kind in ("cpu", "TPU v6 lite", None):
+        with pytest.raises(ValueError, match="no chip profile"):
+            chip_profile_from_host({"jax_device_kind": kind})
 
 
 def test_verify_clean_log(tmp_path):

@@ -25,7 +25,6 @@ from repro.core import (BASELINE, GridCarbonModel, HourlySignal,
                         simulate_campaign_exact, sweep, trace_sweep)
 from repro.core import Campaign
 from repro.core.engine import _band_table, _carbon_table
-from repro.core.engine_jax import _HAS_JAX
 from repro.core.policy import HourlyPolicy
 from repro.core.workload import OEM_CASE_1, OEMWorkload
 
@@ -70,8 +69,6 @@ def test_trace_engine_numpy_backend_matches_jax(calibrated):
     cases = [SweepCase(p, wl, m) for p in (BASELINE, PEAK_AWARE_BOOSTED)]
     cases += [SweepCase(progress_ramp_schedule(0.4, 0.9), wl, m)]
     np_res = trace_sweep(cases, backend="numpy")
-    if not _HAS_JAX:
-        pytest.skip("jax not importable; numpy fallback already exercised")
     jax_res = trace_sweep(cases, backend="jax")
     for a, b in zip(np_res, jax_res):
         assert abs(b.runtime_h / a.runtime_h - 1) < 1e-12, a.policy
@@ -259,10 +256,9 @@ def test_as_trace_coerces_sequences():
     assert as_trace(t) is t
     # arrays exposing a non-callable `.at` indexer (jnp, pandas) are
     # sequences, not Signals — they must be converted, not passed through
-    if _HAS_JAX:
-        import jax.numpy as jnp
-        tj = as_trace(jnp.linspace(0.4, 0.7, 48))
-        assert isinstance(tj, TraceSignal) and len(tj.values) == 48
+    import jax.numpy as jnp
+    tj = as_trace(jnp.linspace(0.4, 0.7, 48))
+    assert isinstance(tj, TraceSignal) and len(tj.values) == 48
     # SignalSet.sample carries traces next to periodic signals
     sigs = default_signals(TimeBands(), GridCarbonModel())
     sigs = type(sigs)(background=sigs.background, carbon=_week_trace())
