@@ -55,6 +55,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import math
 from collections import OrderedDict
 from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
@@ -121,6 +122,12 @@ class ScanStats:
     ("fp64"/"mixed") of the most recent `execute_plan`; and
     `bytes_uploaded` counts the host -> device bytes the chunk launches
     moved (inputs and carried state; divide by `chunks` for per-chunk).
+    `live_slot_work` counts the lane x slot units of the lanes each
+    launch was given, before the JAX backend pads the lane axis to its
+    shape bucket: `1 - live_slot_work / slot_work` is the share of the
+    launched slot-work spent on padding lanes (the two are equal when
+    every launch's live lanes fill their bucket, and on the NumPy
+    backend, which does not pad).
     MPC observability: `replans` counts `replace_tables` calls (one per
     mid-flight re-plan) and `slots_reused` counts the lane x slot units
     of already-executed state carried across those re-plans — work a
@@ -137,6 +144,7 @@ class ScanStats:
     (or call `reset_scan_stats()`) to zero them between measurements.
     """
     slot_work: int = 0            # scan-lane x slot units executed
+    live_slot_work: int = 0       # ... of the live (unpadded) lanes
     chunks: int = 0               # kernel launches
     grouped_lanes: int = 0        # lane x chunk units in coupled groups
     plan_hits: int = 0            # per-case compile cache hits
@@ -184,25 +192,41 @@ def scan_stats(reset: bool = False) -> ScanStats:
 
 def reset_scan_stats() -> None:
     """Zero the counters (including the jit-shape signature set)."""
-    _STATS.slot_work = 0
-    _STATS.chunks = 0
-    _STATS.grouped_lanes = 0
-    _STATS.plan_hits = 0
-    _STATS.plan_misses = 0
-    _STATS.replans = 0
-    _STATS.slots_reused = 0
-    _STATS.disk_hits = 0
-    _STATS.disk_misses = 0
-    _STATS.lanes_recomputed = 0
-    _STATS.lanes_spliced = 0
-    _STATS.requests_seen = 0
-    _STATS.requests_admitted = 0
-    _STATS.requests_rejected = 0
-    _STATS.requests_degraded = 0
-    _STATS.devices_used = 0
-    _STATS.precision_mode = ""
-    _STATS.bytes_uploaded = 0
-    _STATS.jit_shapes = set()
+    fresh = ScanStats()
+    for f in dataclasses.fields(ScanStats):
+        setattr(_STATS, f.name, getattr(fresh, f.name))
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans: while a `jax.profiler` trace runs, each sweep writes its
+# phases into it on the same clock as the device's operations, so every
+# stretch in which the device waits can be put down to host work.
+# ---------------------------------------------------------------------------
+#: Prefix of the engine's span names.
+SPAN_PREFIX = "carina."
+
+#: Process-wide sweep number, the `seq` stat of each `carina.sweep` span.
+_SWEEP_SEQ = itertools.count(1)
+
+
+def _span(name: str, **stats) -> jax.profiler.TraceAnnotation:
+    """The span `carina.<name>` over the block it wraps, `stats` as the
+    event's metadata.  With no trace running it costs under a
+    microsecond; spans mark phases of a sweep and of a chunk launch,
+    never single cases or lanes."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **stats)
+
+
+def sweep_span(cases: int) -> jax.profiler.TraceAnnotation:
+    """The `carina.sweep` span around one request's engine work (plan,
+    execute, summarize), numbered by the process-wide `seq`."""
+    return _span("sweep", cases=cases, seq=next(_SWEEP_SEQ))
+
+
+def _spanned(name: str):
+    """Decorator: every call of the function inside `_span(name)`."""
+    return functools.partial(jax.profiler.annotate_function,
+                             name=SPAN_PREFIX + name)
 
 
 @functools.lru_cache(maxsize=256)       # bounded, same policy as engine.py
@@ -796,6 +820,7 @@ def new_cursor(plan: SweepPlan) -> PlanCursor:
     return PlanCursor(state=state, t0=0, active=np.arange(L))
 
 
+@_spanned("plan")
 def compile_plan(cases: Sequence, price: Optional[Signal] = None, *,
                  slots_per_hour: int = 1, progress_buckets: int = 32,
                  max_days: int = 120,
@@ -910,34 +935,38 @@ def compile_plan(cases: Sequence, price: Optional[Signal] = None, *,
     cache = plancache.get_cache(cache_dir)
     # fresh-process warm starts skip XLA compiles too, not just staging
     enable_persistent_compilation_cache()
-    memo: dict = {}
-    keys = [_fingerprint(c, price, sph, B, max_days, memo) for c in cases]
-    compiled: List[Optional[_CaseCompiled]] = [
-        _memo_get(k) if k is not None else None for k in keys]
-    _STATS.plan_hits += sum(c is not None for c in compiled)
-    missing = [i for i, c in enumerate(compiled) if c is None]
-    batch_digest = (cache.batch_digest(keys)
-                    if cache is not None and len(cases)
-                    and all(k is not None for k in keys) else None)
-    batch_missed = False
-    if missing and batch_digest is not None:
-        # whole-batch warm start: one entry read replaces up to S
-        # per-case reads (the common recurrence shape — the same batch,
-        # verbatim, next cycle in a fresh process)
-        batch = cache.get_batch(batch_digest, len(cases))
-        if batch is not None:
-            for i in missing:
-                compiled[i] = batch[i]
-                _memo_put(keys[i], batch[i])
-            _STATS.disk_hits += len(missing)
-            missing = []
-        else:
-            batch_missed = True
-    for i in missing:
-        compiled[i] = _obtain_case(cases[i], dec_sigs[i], price, sph, B,
-                                   max_days, max_hours, keys[i], cache)
-    if batch_missed:
-        cache.put_batch(batch_digest, compiled)
+    with _span("plan.keys"):
+        memo: dict = {}
+        keys = [_fingerprint(c, price, sph, B, max_days, memo)
+                for c in cases]
+    with _span("plan.lookup"):
+        compiled: List[Optional[_CaseCompiled]] = [
+            _memo_get(k) if k is not None else None for k in keys]
+        _STATS.plan_hits += sum(c is not None for c in compiled)
+        missing = [i for i, c in enumerate(compiled) if c is None]
+        batch_digest = (cache.batch_digest(keys)
+                        if cache is not None and len(cases)
+                        and all(k is not None for k in keys) else None)
+        batch_missed = False
+        if missing and batch_digest is not None:
+            # whole-batch warm start: one entry read replaces up to S
+            # per-case reads (the common recurrence shape — the same
+            # batch, verbatim, next cycle in a fresh process)
+            batch = cache.get_batch(batch_digest, len(cases))
+            if batch is not None:
+                for i in missing:
+                    compiled[i] = batch[i]
+                    _memo_put(keys[i], batch[i])
+                _STATS.disk_hits += len(missing)
+                missing = []
+            else:
+                batch_missed = True
+    with _span("plan.classify"):
+        for i in missing:
+            compiled[i] = _obtain_case(cases[i], dec_sigs[i], price, sph, B,
+                                       max_days, max_hours, keys[i], cache)
+        if batch_missed:
+            cache.put_batch(batch_digest, compiled)
     for c, comp in zip(cases, compiled):
         if comp.stalled:
             raise RuntimeError(
@@ -948,107 +977,108 @@ def compile_plan(cases: Sequence, price: Optional[Signal] = None, *,
                 "at zero intensity")
 
     # ---- lane layout -----------------------------------------------------
-    lane_case: List[int] = []
-    lane_member: List[int] = []
-    lane_table: List[Optional[Tuple[np.ndarray, np.ndarray]]] = []
-    lane_builder: List[Optional[Callable]] = []
-    lane_periodic: List[bool] = []
-    lane_co2: List[Tuple[Signal, ...]] = []
-    case_expanded: List[bool] = []
-    lane_group: List[int] = []
-    for i, (c, comp, ens) in enumerate(zip(cases, compiled, ensembles)):
-        sched = as_schedule(c.schedule)
-        expand = ens is not None and comp.carbon_dep
-        if expand and np.isfinite(caps[case_group[i]]):
-            raise ValueError(
-                f"case {c.name()!r}: a carbon-consulting schedule under a "
-                "carbon ensemble expands into per-member lanes, which "
-                "cannot share a site cap (each member lane is an "
-                "alternative scenario, not a concurrent campaign) — use a "
-                "carbon-blind schedule, a single trace, or drop the cap")
-        case_expanded.append(expand)
-        members = range(E) if expand else (0,)
-        for e in members:
-            lane_case.append(i)
-            lane_group.append(int(case_group[i]))
-            lane_member.append(e)
-            if expand:
-                # per-member decisions: rebuild the table (or builder)
-                # against member e's carbon signal
-                sig_e = carbon_signal(ens.member(e))
-                if comp.periodic:
-                    lane_table.append(
-                        comp.table if comp.prof is not None else
-                        _day_table(c, sched, comp.probe, sig_e, price,
-                                   sph, B))
-                    lane_builder.append(None)
+    with _span("plan.lanes"):
+        lane_case: List[int] = []
+        lane_member: List[int] = []
+        lane_table: List[Optional[Tuple[np.ndarray, np.ndarray]]] = []
+        lane_builder: List[Optional[Callable]] = []
+        lane_periodic: List[bool] = []
+        lane_co2: List[Tuple[Signal, ...]] = []
+        case_expanded: List[bool] = []
+        lane_group: List[int] = []
+        for i, (c, comp, ens) in enumerate(zip(cases, compiled, ensembles)):
+            sched = as_schedule(c.schedule)
+            expand = ens is not None and comp.carbon_dep
+            if expand and np.isfinite(caps[case_group[i]]):
+                raise ValueError(
+                    f"case {c.name()!r}: a carbon-consulting schedule under a "
+                    "carbon ensemble expands into per-member lanes, which "
+                    "cannot share a site cap (each member lane is an "
+                    "alternative scenario, not a concurrent campaign) — use a "
+                    "carbon-blind schedule, a single trace, or drop the cap")
+            case_expanded.append(expand)
+            members = range(E) if expand else (0,)
+            for e in members:
+                lane_case.append(i)
+                lane_group.append(int(case_group[i]))
+                lane_member.append(e)
+                if expand:
+                    # per-member decisions: rebuild the table (or builder)
+                    # against member e's carbon signal
+                    sig_e = carbon_signal(ens.member(e))
+                    if comp.periodic:
+                        lane_table.append(
+                            comp.table if comp.prof is not None else
+                            _day_table(c, sched, comp.probe, sig_e, price,
+                                       sph, B))
+                        lane_builder.append(None)
+                    else:
+                        lane_table.append(None)
+                        lane_builder.append(_chunk_table_builder(
+                            c, sched, comp.probe, sig_e, price, sph, B))
+                    # the member's own trace carbonizes every ensemble column
+                    # (summarize reads the diagonal lane e / member e)
+                    lane_co2.append(tuple(carbon_signal(ens.member(e))
+                                          for _ in range(E)))
                 else:
-                    lane_table.append(None)
-                    lane_builder.append(_chunk_table_builder(
-                        c, sched, comp.probe, sig_e, price, sph, B))
-                # the member's own trace carbonizes every ensemble column
-                # (summarize reads the diagonal lane e / member e)
-                lane_co2.append(tuple(carbon_signal(ens.member(e))
-                                      for _ in range(E)))
-            else:
-                if comp.periodic:
-                    lane_table.append(comp.table)
-                    lane_builder.append(None)
-                else:
-                    lane_table.append(None)
-                    lane_builder.append(_chunk_table_builder(
-                        c, sched, comp.probe, dec_sigs[i], price, sph, B))
-                if ens is not None:
-                    lane_co2.append(tuple(carbon_signal(ens.member(e2))
-                                          for e2 in range(E)))
-                else:
-                    lane_co2.append(tuple(dec_sigs[i] for _ in range(E)))
-            lane_periodic.append(comp.periodic)
+                    if comp.periodic:
+                        lane_table.append(comp.table)
+                        lane_builder.append(None)
+                    else:
+                        lane_table.append(None)
+                        lane_builder.append(_chunk_table_builder(
+                            c, sched, comp.probe, dec_sigs[i], price, sph, B))
+                    if ens is not None:
+                        lane_co2.append(tuple(carbon_signal(ens.member(e2))
+                                              for e2 in range(E)))
+                    else:
+                        lane_co2.append(tuple(dec_sigs[i] for _ in range(E)))
+                lane_periodic.append(comp.periodic)
 
-    lc = np.asarray(lane_case, dtype=int)
-    wl = [cases[i].workload for i in lane_case]
-    mach = [cases[i].machine for i in lane_case]
-    start = np.array([cases[i].start_hour for i in lane_case], dtype=float)
-    g0 = np.floor(start * sph) / sph
-    # periodic decision tables, stacked once so the per-chunk assembly is
-    # one fancy-index slice instead of a per-lane Python loop
-    L = len(lane_case)
-    B_t = max((t[0].shape[1] for t in lane_table if t is not None),
-              default=1)
-    tab_u = np.zeros((L, H, B_t))
-    tab_b = np.ones((L, H, B_t))
-    for lane, t in enumerate(lane_table):
-        if t is not None:
-            u_r, b_r = t
-            tab_u[lane] = u_r if u_r.shape[1] == B_t \
-                else np.broadcast_to(u_r, (H, B_t))
-            tab_b[lane] = b_r if b_r.shape[1] == B_t \
-                else np.broadcast_to(b_r, (H, B_t))
-    return SweepPlan(
-        cases=tuple(cases), price=price, sph=sph, B=B, max_days=int(max_days),
-        E=E, case_ensemble=ensembles, case_expanded=case_expanded,
-        lane_case=lc, lane_member=np.asarray(lane_member, dtype=int),
-        lane_table=lane_table, lane_builder=lane_builder,
-        lane_periodic=np.asarray(lane_periodic, dtype=bool),
-        tab_u=tab_u, tab_b=tab_b, tab_buckets=B_t,
-        lane_co2_sigs=lane_co2,
-        n_scen=np.array([float(w.n_scenarios) for w in wl]),
-        rate=np.array([w.rate_at_full for w in wl]),
-        oh=np.array([w.batch_overhead_s for w in wl]),
-        idle=np.array([m.idle_w for m in mach]),
-        dyn=np.array([m.dyn_w for m in mach]),
-        alpha=np.array([m.alpha for m in mach]),
-        gamma=np.array([m.gamma for m in mach]),
-        ohfrac=np.array([m.overhead_w_frac for m in mach]),
-        start=start, g0=g0,
-        s0=np.round(g0 * sph).astype(int) % H,
-        bg_day=np.stack([_bg_table(cases[i].bands, sph)
-                         for i in lane_case]),
-        est_h=max(comp.est_h for comp in compiled),
-        precision=precision,
-        group_sizes=group_sizes, case_group=case_group,
-        lane_group=np.asarray(lane_group, dtype=int),
-        group_cap_kw=caps, group_office_kw=office)
+        lc = np.asarray(lane_case, dtype=int)
+        wl = [cases[i].workload for i in lane_case]
+        mach = [cases[i].machine for i in lane_case]
+        start = np.array([cases[i].start_hour for i in lane_case], dtype=float)
+        g0 = np.floor(start * sph) / sph
+        # periodic decision tables, stacked once so the per-chunk assembly is
+        # one fancy-index slice instead of a per-lane Python loop
+        L = len(lane_case)
+        B_t = max((t[0].shape[1] for t in lane_table if t is not None),
+                  default=1)
+        tab_u = np.zeros((L, H, B_t))
+        tab_b = np.ones((L, H, B_t))
+        for lane, t in enumerate(lane_table):
+            if t is not None:
+                u_r, b_r = t
+                tab_u[lane] = u_r if u_r.shape[1] == B_t \
+                    else np.broadcast_to(u_r, (H, B_t))
+                tab_b[lane] = b_r if b_r.shape[1] == B_t \
+                    else np.broadcast_to(b_r, (H, B_t))
+        return SweepPlan(
+            cases=tuple(cases), price=price, sph=sph, B=B, max_days=int(max_days),
+            E=E, case_ensemble=ensembles, case_expanded=case_expanded,
+            lane_case=lc, lane_member=np.asarray(lane_member, dtype=int),
+            lane_table=lane_table, lane_builder=lane_builder,
+            lane_periodic=np.asarray(lane_periodic, dtype=bool),
+            tab_u=tab_u, tab_b=tab_b, tab_buckets=B_t,
+            lane_co2_sigs=lane_co2,
+            n_scen=np.array([float(w.n_scenarios) for w in wl]),
+            rate=np.array([w.rate_at_full for w in wl]),
+            oh=np.array([w.batch_overhead_s for w in wl]),
+            idle=np.array([m.idle_w for m in mach]),
+            dyn=np.array([m.dyn_w for m in mach]),
+            alpha=np.array([m.alpha for m in mach]),
+            gamma=np.array([m.gamma for m in mach]),
+            ohfrac=np.array([m.overhead_w_frac for m in mach]),
+            start=start, g0=g0,
+            s0=np.round(g0 * sph).astype(int) % H,
+            bg_day=np.stack([_bg_table(cases[i].bands, sph)
+                             for i in lane_case]),
+            est_h=max(comp.est_h for comp in compiled),
+            precision=precision,
+            group_sizes=group_sizes, case_group=case_group,
+            lane_group=np.asarray(lane_group, dtype=int),
+            group_cap_kw=caps, group_office_kw=office)
 
 
 def _normalize_replace_maps(plan: SweepPlan, schedules, carbon
@@ -1478,6 +1508,7 @@ def _scan_chunk_np(u_tab, b_tab, rowidx, bg, cf, pr, lens, state, scalars,
         co2 = co2 + e[:, None] * cf[:, :, t]
         cost = cost + e * pr[:, t]
     _STATS.slot_work += A * steps
+    _STATS.live_slot_work += A * steps
     return remaining, rt, kwh, co2, cost
 
 
@@ -1538,6 +1569,7 @@ def _scan_chunk_np_coupled(u_tab, b_tab, rowidx, bg, cf, pr, lens,
         co2 = co2 + e[:, None] * cf[:, :, t]
         cost = cost + e * pr[:, t]
     _STATS.slot_work += A * steps
+    _STATS.live_slot_work += A * steps
     return remaining, rt, kwh, co2, cost, speak
 
 
@@ -1714,6 +1746,7 @@ def _resolve_devices(devices, use_jax: bool) -> int:
     return min(n, avail)
 
 
+@_spanned("chunk.upload")
 def _upload(*pairs) -> tuple:
     """(host array, dtype) pairs -> device arrays, counting the bytes
     moved (call under `enable_x64`; dtype None keeps the array's own)."""
@@ -1722,6 +1755,14 @@ def _upload(*pairs) -> tuple:
     return out
 
 
+@_spanned("chunk.fetch")
+def _fetch(out) -> tuple:
+    """A launch's device results -> host arrays: waits for the chunk
+    kernel, then copies its outputs to the host."""
+    return tuple(np.asarray(o) for o in out)
+
+
+@_spanned("chunk")
 def _run_chunk(plan: SweepPlan, active: np.ndarray, inputs, state_slices,
                use_jax: bool, n_dev: int = 1) -> tuple:
     """Execute one chunk for the active lanes, padding the batch to
@@ -1779,17 +1820,19 @@ def _run_chunk(plan: SweepPlan, active: np.ndarray, inputs, state_slices,
     _STATS.jit_shapes.add(sig)
     _STATS.chunks += 1
     _STATS.slot_work += Ap * C
+    _STATS.live_slot_work += A * C
     _STATS.devices_used = max(_STATS.devices_used, n_dev)
     with enable_x64():
         ins = _upload((u_tab, cdt), (b_tab, cdt), (rowidx, None), (bg, cdt),
                       (cf, cdt), (pr, cdt), (lens, cdt))
         st = _upload(*((a, adt) for a in state_slices))
         sc = _upload(*((a, cdt) for a in scalars))
-        if n_dev > 1:
-            out = _sharded_plain(n_dev, Bg)(*ins, *st, *sc)
-        else:
-            out = _scan_chunk_jax(*ins, *st, *sc, B=Bg)
-    out = tuple(np.asarray(o) for o in out)
+        with _span("chunk.launch"):
+            if n_dev > 1:
+                out = _sharded_plain(n_dev, Bg)(*ins, *st, *sc)
+            else:
+                out = _scan_chunk_jax(*ins, *st, *sc, B=Bg)
+    out = _fetch(out)
     if Ap != A:
         out = tuple(o[:A] for o in out)
     return out
@@ -1866,15 +1909,17 @@ def _run_chunk_coupled(plan: SweepPlan, active: np.ndarray, inputs,
     _STATS.jit_shapes.add(sig)
     _STATS.chunks += 1
     _STATS.slot_work += Ap * C
+    _STATS.live_slot_work += A * C
     _STATS.devices_used = max(_STATS.devices_used, 1)
     with enable_x64():
-        out = _scan_chunk_jax_coupled(
-            *_upload((u_tab, cdt), (b_tab, cdt), (rowidx, None), (bg, cdt),
-                     (cf, cdt), (pr, cdt), (lens, cdt), (gid, None),
-                     (cap_g, cdt), (office, cdt)),
-            *_upload(*((a, adt) for a in state_slices)),
-            *_upload(*((a, cdt) for a in scalars)), B=Bg, G=Gp)
-    out = tuple(np.asarray(o) for o in out)
+        ins = _upload((u_tab, cdt), (b_tab, cdt), (rowidx, None), (bg, cdt),
+                      (cf, cdt), (pr, cdt), (lens, cdt), (gid, None),
+                      (cap_g, cdt), (office, cdt))
+        st = _upload(*((a, adt) for a in state_slices))
+        sc = _upload(*((a, cdt) for a in scalars))
+        with _span("chunk.launch"):
+            out = _scan_chunk_jax_coupled(*ins, *st, *sc, B=Bg, G=Gp)
+    out = _fetch(out)
     if Ap != A:
         out = tuple(o[:A] for o in out)
     return out
@@ -1946,9 +1991,10 @@ def _run_chunk_coupled_sharded(plan: SweepPlan, inputs, state_slices,
     _STATS.jit_shapes.add(sig)
     _STATS.chunks += 1
     _STATS.slot_work += n_dev * Ld * C
+    _STATS.live_slot_work += A * C
     _STATS.devices_used = max(_STATS.devices_used, n_dev)
     with enable_x64():
-        out = _sharded_coupled(n_dev, Bg, Gp)(*_upload(
+        args = _upload(
             (stack_lane(u_tab), cdt), (stack_lane(b_tab, 1.0), cdt),
             (stack_lane(rowidx), None), (stack_lane(bg), cdt),
             (stack_lane(cf), cdt), (stack_lane(pr), cdt),
@@ -1959,16 +2005,15 @@ def _run_chunk_coupled_sharded(plan: SweepPlan, inputs, state_slices,
             (stack_lane(n_scen, 1.0), cdt), (stack_lane(rate), cdt),
             (stack_lane(oh), cdt), (stack_lane(idle), cdt),
             (stack_lane(dyn), cdt), (stack_lane(alpha, 1.0), cdt),
-            (stack_lane(gamma), cdt), (stack_lane(ohfrac), cdt)))
-    final = []
-    for o in out:
-        o = np.asarray(o)
-        final.append(np.concatenate(
-            [o[d * Ld:d * Ld + (lane_hi[d] - lane_lo[d])]
-             for d in range(n_dev)]))
-    return tuple(final)
+            (stack_lane(gamma), cdt), (stack_lane(ohfrac), cdt))
+        with _span("chunk.launch"):
+            out = _sharded_coupled(n_dev, Bg, Gp)(*args)
+    return tuple(np.concatenate([o[d * Ld:d * Ld + (lane_hi[d] - lane_lo[d])]
+                                 for d in range(n_dev)])
+                 for o in _fetch(out))
 
 
+@_spanned("chunk.inputs")
 def _chunk_inputs(plan: SweepPlan, active: np.ndarray, t0: int,
                   C: int) -> tuple:
     """Assemble the per-slot inputs for global slots [t0, t0 + C) of the
@@ -2089,6 +2134,7 @@ def execute_plan(plan: SweepPlan, *, backend: Optional[str] = None,
                             devices=devices).state
 
 
+@_spanned("execute")
 def execute_interval(plan: SweepPlan, cursor: Optional[PlanCursor] = None, *,
                      until_slot: Optional[int] = None,
                      backend: Optional[str] = None,
@@ -2168,6 +2214,7 @@ def execute_interval(plan: SweepPlan, cursor: Optional[PlanCursor] = None, *,
                       t0=t0, active=active)
 
 
+@_spanned("execute")
 def _execute_monolithic(plan: SweepPlan, use_jax: bool,
                         n_dev: int = 1) -> _ScanState:
     """The pre-chunking behaviour: scan everything from t=0 over one
@@ -2208,6 +2255,7 @@ def _execute_monolithic(plan: SweepPlan, use_jax: bool,
         T = min(T * 2, max_slots)
 
 
+@_spanned("summarize")
 def summarize_plan(plan: SweepPlan, state: _ScanState) -> List[SimResult]:
     """Fold the final scan state into one `SimResult` per case.
 
@@ -2810,11 +2858,13 @@ def trace_sweep(cases: Sequence, price: Optional[Signal] = None, *,
     """
     if not len(cases):
         return []
-    plan = compile_plan(cases, price, slots_per_hour=slots_per_hour,
-                        progress_buckets=progress_buckets, max_days=max_days,
-                        group_sizes=group_sizes, group_caps_kw=group_caps_kw,
-                        group_office_kw=group_office_kw,
-                        precision=precision, cache_dir=cache_dir)
-    state = execute_plan(plan, backend=backend, chunk_days=chunk_days,
-                         mode=mode, devices=devices)
-    return summarize_plan(plan, state)
+    with sweep_span(len(cases)):
+        plan = compile_plan(cases, price, slots_per_hour=slots_per_hour,
+                            progress_buckets=progress_buckets,
+                            max_days=max_days, group_sizes=group_sizes,
+                            group_caps_kw=group_caps_kw,
+                            group_office_kw=group_office_kw,
+                            precision=precision, cache_dir=cache_dir)
+        state = execute_plan(plan, backend=backend, chunk_days=chunk_days,
+                             mode=mode, devices=devices)
+        return summarize_plan(plan, state)

@@ -180,29 +180,31 @@ def fleet_sweep(fleet_cases: Sequence[Sequence[SweepCase]],
         return out
 
     from repro.core.engine_jax import compile_plan, execute_plan, \
-        summarize_plan
+        summarize_plan, sweep_span
     sph = 1
     for c in flat:
         sph = math.lcm(sph, case_slots_per_hour(c))
     G = len(fleet_cases)
-    plan = compile_plan(flat, price, slots_per_hour=sph,
-                        progress_buckets=progress_buckets, max_days=max_days,
-                        group_sizes=sizes,
-                        group_caps_kw=[site.power_cap_kw] * G,
-                        group_office_kw=[site.office_kw] * G,
-                        precision=precision, cache_dir=cache_dir)
-    state = execute_plan(plan, backend=backend, chunk_days=chunk_days,
-                         devices=devices)
-    res = summarize_plan(plan, state)
-    out = []
-    i = 0
-    for g, (name, M) in enumerate(zip(names, sizes)):
-        members = res[i:i + M]
-        lanes = np.flatnonzero(plan.lane_group == g)
-        peak = float(state.site_kw_peak[lanes].max())
-        out.append(FleetResult(policy=name, campaigns=members,
-                               site=_rollup(name, members, peak_kw=peak)))
-        i += M
+    with sweep_span(len(flat)):
+        plan = compile_plan(flat, price, slots_per_hour=sph,
+                            progress_buckets=progress_buckets,
+                            max_days=max_days, group_sizes=sizes,
+                            group_caps_kw=[site.power_cap_kw] * G,
+                            group_office_kw=[site.office_kw] * G,
+                            precision=precision, cache_dir=cache_dir)
+        state = execute_plan(plan, backend=backend, chunk_days=chunk_days,
+                             devices=devices)
+        res = summarize_plan(plan, state)
+        out = []
+        i = 0
+        for g, (name, M) in enumerate(zip(names, sizes)):
+            members = res[i:i + M]
+            lanes = np.flatnonzero(plan.lane_group == g)
+            peak = float(state.site_kw_peak[lanes].max())
+            out.append(FleetResult(policy=name, campaigns=members,
+                                   site=_rollup(name, members,
+                                                peak_kw=peak)))
+            i += M
     return out
 
 
