@@ -500,6 +500,17 @@ class _Opaque(Exception):
 
 _OPAQUE_FROZEN = object()     # memoized "this component is opaque" marker
 
+#: Stands where the frozen carbon would in the key of a `carbon_blind` case.
+_CARBON_BLIND = "carbon-blind"
+
+
+def _carbon_blind(sched) -> bool:
+    """Whether `sched`'s own class declares `carbon_blind = True`.  The
+    flag is never inherited: a subclass may override decide() to read
+    `ctx.carbon_factor`, so it keeps its carbon in the key until it
+    declares the contract itself."""
+    return vars(type(sched)).get("carbon_blind", False) is True
+
 
 def _freeze(obj):
     """Recursively lower a fingerprint component to a hashable value:
@@ -535,6 +546,16 @@ def _fingerprint(case, price, sph: int, B: int, max_days: int,
     """Hashable value identity of one case's compilation inputs, or None
     when a component is opaque (then the case is compiled fresh).
 
+    The carbon enters the key unless the schedule's own class declares
+    `carbon_blind` (its decisions never read `ctx.carbon_factor`: the
+    bundled `Policy`, `HourlyPolicy`, `ParametricSchedule` and
+    `DeadlineSchedule`; a subclass does not inherit it).  Such a case's compile artifact is the same under
+    every carbon signal, so a fixed marker stands in its place and the
+    carbon is neither frozen nor hashed: the same candidate re-scored
+    against a new forecast hits the memo.  The carbon its lanes are
+    scored against never comes from the memo (`compile_plan` takes it
+    from the case).  Every other schedule keeps its carbon in the key.
+
     `memo` (id -> (obj, frozen)) de-duplicates the freeze of components
     shared across a batch — a 1000-case sweep over one workload/machine/
     trace freezes each shared object once, not 1000 times.  The memo
@@ -555,10 +576,12 @@ def _fingerprint(case, price, sph: int, B: int, max_days: int,
             raise _Opaque
         return entry[1]
 
+    blind = _carbon_blind(as_schedule(case.schedule))
     try:
         return (freeze(case.schedule), freeze(case.workload),
                 freeze(case.machine), freeze(case.bands),
-                freeze(case.carbon), case.start_hour, case.deadline_h,
+                _CARBON_BLIND if blind else freeze(case.carbon),
+                case.start_hour, case.deadline_h,
                 freeze(price) if price is not None else None,
                 sph, B, max_days)
     except _Opaque:
@@ -634,7 +657,10 @@ def _obtain_case(case, dec_sig, price, sph: int, B: int, max_days: int,
     memo, then the disk store, then `_compile_case` (write-through to
     both layers).  Opaque-fingerprint cases (key None) bypass both
     layers entirely — no entry is ever stored for them, so a
-    closure-bearing schedule can never poison the cache."""
+    closure-bearing schedule can never poison the cache.  The key of a
+    `carbon_blind` schedule holds no carbon (`_fingerprint`), so its
+    artifact, compiled against one forecast's `dec_sig`, serves every
+    other forecast."""
     comp = _memo_get(key) if key is not None else None
     if comp is not None:
         _STATS.plan_hits += 1
@@ -674,6 +700,13 @@ def _compile_case(case, dec_sig, price, sph: int, B: int,
                              stalled=_table_stalled(case, table, sph))
     probe = _probe(sched, _ctx_factory(case, dec_sig, price),
                    _case_g0(case, sph), max_hours)
+    if probe.carbon_dep and _carbon_blind(sched):
+        raise ValueError(
+            f"schedule {getattr(sched, 'name', type(sched).__name__)!r} "
+            f"({type(sched).__name__}) declares carbon_blind but its "
+            "decisions move with ctx.carbon_factor; its compile artifact "
+            "would be served under other carbon signals — set "
+            "carbon_blind = False on its class")
     est = _estimate_hours(case, None, probe, max_hours, sph)
     # decide_grid tables are exact per-slot and cheap to rebuild per
     # chunk, so schedules implementing it only get the compact
@@ -834,7 +867,11 @@ def compile_plan(cases: Sequence, price: Optional[Signal] = None, *,
     Per-case classification (closed-form profile / probe / decide_grid)
     is memoized by case fingerprint across calls, so re-sweeping the
     same cases — or re-evaluating an optimizer's warm-start loop — skips
-    the Python probing entirely.  `cache_dir` (default: the
+    the Python probing entirely.  The fingerprint of a case whose
+    schedule's own class declares `carbon_blind` leaves its carbon out, so the same
+    candidates re-scored against a new forecast (the recurring refresh)
+    are memo hits too; each lane is still scored against the case's own
+    carbon.  `cache_dir` (default: the
     ``CARINA_PLAN_CACHE`` environment variable; caching off when both
     are unset) adds the persistent layer: compile artifacts are also
     served from / written through to a disk-backed content-addressed
@@ -961,7 +998,8 @@ def compile_plan(cases: Sequence, price: Optional[Signal] = None, *,
                 missing = []
             else:
                 batch_missed = True
-    with _span("plan.classify"):
+    with _span("plan.classify", hits=len(cases) - len(missing),
+               misses=len(missing)):
         for i in missing:
             compiled[i] = _obtain_case(cases[i], dec_sigs[i], price, sph, B,
                                        max_days, max_hours, keys[i], cache)

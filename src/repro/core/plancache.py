@@ -13,7 +13,8 @@ Store layout and contract:
   * **Content-addressed keys.**  An entry's filename is the SHA-256 of
     its case fingerprint (the same `_freeze` value identity the
     in-memory memo uses: schedule/workload/machine/bands/carbon by
-    field values, price, sph/B/max_days) salted with `SCHEMA_VERSION`.
+    field values — the carbon left out for a `carbon_blind` schedule —
+    price, sph/B/max_days) salted with `SCHEMA_VERSION`.
     Bumping the schema version orphans every old entry — versioned
     invalidation without a migration step (orphans age out via the LRU
     sweep).  Cases whose fingerprint is opaque (closure-bearing
@@ -54,9 +55,11 @@ import numpy as np
 
 #: Version salt of the on-disk entry format *and* of the compile
 #: semantics it captures.  Bump whenever `_CaseCompiled`, `ProbeInfo`,
-#: probing, or table lowering change meaning — old entries then simply
-#: never match (versioned invalidation) and age out of the store.
-SCHEMA_VERSION = 1
+#: probing, table lowering, or the form of the case key change meaning —
+#: old entries then simply never match (versioned invalidation) and age
+#: out of the store.  2: a `carbon_blind` schedule's key holds a marker
+#: in place of its carbon.
+SCHEMA_VERSION = 2
 
 _DEFAULT_MAX_MB = 512.0
 

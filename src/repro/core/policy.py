@@ -72,6 +72,15 @@ class Policy:
     batch_size: int = 50
     low_priority: bool = False
 
+    #: Contract flag for the trace engine's compile memo (as on
+    #: `ParametricSchedule`): decide() reads the band only, never
+    #: `ctx.carbon_factor`, so the memo key leaves the carbon out.
+    #: Carbon-aware policies bake the carbon into their intensities when
+    #: built, which the key holds.  The engine reads the flag from the
+    #: schedule's own class, never inherited: a subclass keeps its carbon
+    #: in the key unless it declares the flag itself.
+    carbon_blind = True
+
     def intensity_at(self, band: str) -> float:
         u = self.intensity[band]
         return u * 0.82 if self.low_priority else u
@@ -134,6 +143,9 @@ class HourlyPolicy(Policy):
     def intensity_at_hour(self, hour: float) -> float:
         u = self.hourly_intensity[math.floor(hour) % 24]
         return u * 0.82 if self.low_priority else u
+
+    #: decide() reads the hour of day and the band only (see `Policy`).
+    carbon_blind = True
 
     # ---- Schedule protocol -------------------------------------------------
     def decide(self, ctx: SchedulingContext) -> Decision:

@@ -148,6 +148,11 @@ class DeadlineSchedule:
     batch_size: int = 50
     name: str = "deadline_pace"
 
+    #: Contract flag for the compile memo (see `ParametricSchedule`):
+    #: `_intensity` reads elapsed time, progress and the deadline only,
+    #: never `ctx.carbon_factor`, so the memo key leaves the carbon out.
+    carbon_blind = True
+
     def _intensity(self, elapsed_h, progress, ctx_deadline_h):
         dl = self.deadline_h if self.deadline_h > 0.0 else ctx_deadline_h
         if dl <= 0.0:
@@ -246,6 +251,13 @@ class ParametricSchedule:
     #: by declaring the same attribute; without it they keep exact
     #: per-slot tables.
     periodic_decisions = True
+    #: Contract flag for the compile memo: decide() never reads
+    #: `ctx.carbon_factor`, so a case's compile artifact is the same under
+    #: every carbon signal and its memo key leaves the carbon out (a
+    #: re-scored candidate hits the memo whatever forecast arrives).  The
+    #: engine reads it from the schedule's own class, never inherited: a
+    #: subclass keeps its carbon in the key unless it declares the flag.
+    carbon_blind = True
 
     def __post_init__(self):
         n = len(self.logits)

@@ -5,11 +5,14 @@ sweep, and (in a child process with four virtual devices) a lane-sharded
 sweep and a sharded capped fleet sweep must hold every `carina.*` span,
 each inside its parent and every one inside its `carina.sweep`, at most
 8 + 8 x chunks of them per sweep whatever the number of cases.
-`live_slot_work` counts the unpadded lanes of each launch, never more
-than `slot_work`, which counts them padded to their shape bucket.
+`carina.plan.classify` carries the cases the memo served (`hits`) and
+those it had to obtain (`misses`).  `live_slot_work` counts the unpadded
+lanes of each launch, never more than `slot_work`, which counts them
+padded to their shape bucket.
 """
 import dataclasses
 import glob
+import math
 import json
 import os
 import subprocess
@@ -120,6 +123,31 @@ def test_spans_of_a_plain_and_a_capped_fleet_sweep(calibrated, tmp_path):
     (n5, c5), (n40, c40), _ = (per_sweep[k] for k in sorted(per_sweep))
     # eight times the cases, no more spans than one more chunk brings
     assert n40 - n5 <= 8 * max(c40 - c5, 0)
+
+
+def test_classify_span_carries_memo_hits_and_misses(calibrated, tmp_path):
+    from repro.core.engine_jax import clear_plan_cache
+    from repro.core.signal import trace_windows
+
+    wl1, _, m = calibrated
+    year = [0.45 + 0.1 * math.sin(h / 17.0) + 1e-3 * h
+            for h in range(24 * 10)]
+    scheds = [BASELINE, PEAK_AWARE_BOOSTED, constant_schedule(0.6)]
+
+    def cases(day):            # a new forecast each day
+        ens = trace_windows(year[24 * day:24 * day + 192], 96, 48)
+        return [SweepCase(s, wl1, m, carbon=ens) for s in scheds]
+
+    def sweeps():
+        trace_sweep(cases(0))
+        trace_sweep(cases(1))
+
+    clear_plan_cache()
+    spans = record(sweeps, str(tmp_path))
+    classify = sorted((s for s in spans if s[2] == "plan.classify"),
+                      key=lambda s: s[0])
+    assert [(c[4]["hits"], c[4]["misses"]) for c in classify] == \
+        [(0, 3), (3, 0)]
 
 
 def test_live_slot_work_counts_unpadded_lanes(calibrated):

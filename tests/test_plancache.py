@@ -11,7 +11,13 @@ acceptance bar):
   opaque-fingerprint schedules bypass both layers without poisoning
   the store, corrupted entries and schema-version drift recompile
   instead of crashing, `plan_cache_info`/`clear_plan_cache` reset the
-  new counters, and the disk store's size-bounded LRU eviction.
+  new counters, and the disk store's size-bounded LRU eviction;
+* carbon-blind keys: a schedule whose type declares `carbon_blind`
+  hits the memo under a new forecast, bitwise equal to a cold compile;
+  a carbon-consulting schedule (a subclass of a declared family
+  included: the flag is not inherited) misses however its probe read
+  the first forecast; a schedule that declares the flag but reads
+  carbon raises.
 """
 import dataclasses
 import json
@@ -28,7 +34,10 @@ from repro.core import (MachineProfile, SweepCase, TraceSignal,
                         trace_sweep)
 from repro.core import engine_jax as ej
 from repro.core import plancache
-from repro.core.schedule import FunctionSchedule
+from repro.core.policy import BANDS, Policy, hourly_schedule
+from repro.core.schedule import (CarbonGateSchedule, Decision,
+                                 FunctionSchedule, ParametricSchedule,
+                                 deadline_schedule)
 from repro.core.workload import OEM_CASE_1
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -474,3 +483,153 @@ def test_subset_plan_refuses_split_coupled_group(calibrated):
     plan = ej.compile_plan(cases, group_sizes=[3], group_caps_kw=[2.0])
     with pytest.raises(ValueError, match="whole"):
         ej._subset_plan(plan, [1])
+
+
+# ---------------------------------------------------------------------------
+# Carbon-blind keys: the recurring refresh re-scores the same candidates
+# against each new forecast
+# ---------------------------------------------------------------------------
+def _forecast(seed: int, members: int = 3, hours: int = 96,
+              level: float = 0.45, swing: float = 0.3):
+    """A `members`-window carbon ensemble around `level`."""
+    rng = np.random.RandomState(seed)
+    h = np.arange(hours)
+    rows = [level * (1.0 + swing * np.sin(2 * np.pi * (h + 5 * e) / 24.0)
+                     + 0.05 * rng.rand(hours)) for e in range(members)]
+    return as_ensemble(np.asarray(rows), name=f"forecast{seed}")
+
+
+def _candidates(calibrated, carbon):
+    """Three parametric and three deadline-paced candidates, as the
+    refresh re-scores them, each a campaign of one to three days."""
+    wl, m = calibrated
+    wl = dataclasses.replace(wl, n_scenarios=300_000.0)
+    rng = np.random.RandomState(11)
+    scheds = [ParametricSchedule(tuple(float(v) for v in rng.randn(24)),
+                                 u_min=0.45, name=f"parametric-{j}")
+              for j in range(3)]
+    scheds += [deadline_schedule(d, name=f"deadline-{d:g}")
+               for d in (30.0, 45.0, 70.0)]
+    return [SweepCase(s, wl, m, carbon=carbon, label=s.name)
+            for s in scheds]
+
+
+def _answers(results):
+    return [_res_key(r) + tuple(r.co2_ensemble.samples) for r in results]
+
+
+def test_carbon_blind_candidates_hit_across_forecasts_bitwise(calibrated):
+    a, b = _forecast(1), _forecast(2)
+    ej.clear_plan_cache()
+    under_a = trace_sweep(_candidates(calibrated, a))
+    ej.reset_scan_stats()
+    warm = trace_sweep(_candidates(calibrated, b))
+    s = ej.scan_stats()
+    assert (s.plan_hits, s.plan_misses) == (6, 0), \
+        "a new forecast must not re-classify carbon-blind candidates"
+    ej.clear_plan_cache()
+    cold = trace_sweep(_candidates(calibrated, b))
+    assert ej.scan_stats().plan_misses == 6
+    assert _answers(warm) == _answers(cold)
+    # the forecast really moves the answers: none came from the memo
+    assert all(x[2] != y[2] for x, y in zip(_answers(warm),
+                                            _answers(under_a)))
+
+
+@dataclasses.dataclass(frozen=True)
+class _DirtyHourPolicy(Policy):
+    """A `Policy` subclass whose decide() throttles to `u_dirty` while the
+    grid is dirtier than `threshold`: it reads carbon and declares
+    nothing, so `Policy`'s own `carbon_blind` must not reach it."""
+    threshold: float = 0.6
+    u_dirty: float = 0.2
+
+    def decide(self, ctx):
+        if ctx.carbon_factor > self.threshold:
+            return Decision(self.u_dirty, self.batch_size)
+        return super().decide(ctx)
+
+
+@dataclasses.dataclass(frozen=True)
+class _SubParametric(ParametricSchedule):
+    """Overrides nothing; the flag is read from the class itself."""
+
+
+@pytest.mark.parametrize("sched", [
+    CarbonGateSchedule(threshold=0.6, u_low=0.2, u_high=0.95),
+    _DirtyHourPolicy("dirty_hour", {b: 0.95 for b in BANDS}),
+], ids=["carbon_gate", "policy_subclass"])
+def test_carbon_reading_schedule_misses_although_its_probe_read_no_carbon(
+        calibrated, sched):
+    """Forecast A never brings the threshold within the probe's
+    perturbation (`carbon_dep` false); forecast B crosses it.  Neither
+    schedule's own class declares `carbon_blind`, so B misses the memo
+    and answers as a cold compile, throttled in B's dirty hours."""
+    wl, m = calibrated
+    wl = dataclasses.replace(wl, n_scenarios=300_000.0)
+    a = _forecast(1, level=0.28, swing=0.1)      # perturbed at most 0.52
+    b = _forecast(2, level=0.6, swing=0.5)       # 0.3 to 0.93
+    plan = ej.compile_plan([SweepCase(sched, wl, m, carbon=a)])
+    assert plan.case_expanded == [False], "the probe read no carbon in A"
+    under_a = trace_sweep([SweepCase(sched, wl, m, carbon=a)])
+    ej.reset_scan_stats()
+    warm = trace_sweep([SweepCase(sched, wl, m, carbon=b)])
+    s = ej.scan_stats()
+    assert (s.plan_hits, s.plan_misses) == (0, 1)
+    ej.clear_plan_cache()
+    cold = trace_sweep([SweepCase(sched, wl, m, carbon=b)])
+    assert _answers(warm) == _answers(cold)
+    assert warm[0].runtime_h > under_a[0].runtime_h
+
+
+@pytest.mark.parametrize("sched, blind", [
+    (ParametricSchedule((0.3,) * 24), True),
+    (deadline_schedule(12.0), True),
+    (constant_schedule(0.7), True),
+    (hourly_schedule("hourly", [0.5] * 12 + [0.9] * 12), True),
+    (CarbonGateSchedule(threshold=0.5), False),
+    (_DirtyHourPolicy("dirty_hour", {b: 0.7 for b in BANDS}), False),
+    (_SubParametric((0.3,) * 24), False),
+], ids=["parametric", "deadline", "policy", "hourly", "carbon_gate",
+        "policy_subclass", "parametric_subclass"])
+def test_fingerprint_leaves_carbon_out_for_declared_families(
+        calibrated, sched, blind):
+    wl, m = calibrated
+    keys = {ej._fingerprint(SweepCase(sched, wl, m, carbon=c), None, 1, 32,
+                            120)
+            for c in (_forecast(1), _forecast(2), _week_trace(), None)}
+    assert None not in keys
+    assert len(keys) == (1 if blind else 4)
+
+
+@dataclasses.dataclass(frozen=True)
+class _MislabelledGate(CarbonGateSchedule):
+    carbon_blind = True          # wrong: the gate reads ctx.carbon_factor
+
+
+def test_carbon_blind_schedule_that_reads_carbon_raises(calibrated):
+    wl, m = calibrated
+    wl = dataclasses.replace(wl, n_scenarios=600.0)
+    case = SweepCase(_MislabelledGate(threshold=0.45, name="mislabelled"),
+                     wl, m, carbon=_week_trace())
+    ej.clear_plan_cache()
+    with pytest.raises(ValueError, match="mislabelled.*carbon_blind"):
+        trace_sweep([case])
+    assert ej.plan_cache_info().mem_entries == 0, "nothing may be cached"
+
+
+def test_replace_tables_carbon_delta_hits_for_blind_schedules(calibrated):
+    """The re-plan path keys through `_fingerprint` too: new carbon on a
+    carbon-blind case is a memo hit, and the re-planned sweep answers as
+    a cold compile under the new carbon."""
+    a, b = _forecast(1), _forecast(2)
+    ej.clear_plan_cache()
+    plan = ej.compile_plan(_candidates(calibrated, a))
+    ej.reset_scan_stats()
+    new = ej.replace_tables(plan, carbon=b)
+    s = ej.scan_stats()
+    assert (s.plan_hits, s.plan_misses) == (6, 0)
+    replanned = ej.summarize_plan(new, ej.execute_plan(new))
+    ej.clear_plan_cache()
+    cold = trace_sweep(_candidates(calibrated, b))
+    assert _answers(replanned) == _answers(cold)
