@@ -33,13 +33,13 @@ def readings(c: dict, seeds: List[int], requests: int, precision: str,
              carina) -> List[Dict]:
     """One reading per seed: {seed, precision, seconds, request_s (each
     request's host-clock seconds), checks}."""
-    from clients import CLIENTS
+    from clients import client_class
+    cls = client_class(c["spec"]["kind"], c["root"])
     out = []
     for seed in seeds:
         t = time.perf_counter()
-        client = CLIENTS[c["spec"]["kind"]](carina, c["cfg"], c["spec"],
-                                            seed, c["cell"]["chips"],
-                                            precision=precision)
+        client = cls(carina, c["cfg"], c["spec"], seed, c["cell"]["chips"],
+                     precision=precision)
         client.build()
         took = []
         for k in range(1, requests + 1):

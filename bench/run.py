@@ -4,9 +4,10 @@
 
 The cell (`workloads` entry of BENCHMARK.json at the checkout's root)
 names a configuration (`bench/configs/<name>.json`) and a traffic mix
-(`bench/traffic/<traffic>.json`); the mix's `kind` picks a request client
-(`clients.py`).  Per-layer metrics are read by `bench/metrics/<name>.py`,
-each found by its name in BENCHMARK.json.
+(`bench/traffic/<traffic>.json`); the mix's `kind` names its request
+client, `bench/kinds/<kind>.py` (`clients.client_class`).  Per-layer
+metrics are read by `bench/metrics/<name>.py`, each found by its name in
+BENCHMARK.json.  So a cell of a new deployment is new files only.
 
 A run: set up (inputs from the seed, warm-up requests that bring the
 program to steady state, compilation served from the compile cache at
@@ -28,7 +29,6 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import glob  # noqa: E402
 import importlib  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -110,11 +110,8 @@ def resolve(root: str, workload: str, manifest: Optional[dict] = None
 
 def load_reader(path: str):
     """The `read(run)` function of one per-layer metric file."""
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + os.path.basename(path)[:-3].replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    from clients import load_attr
+    return load_attr(os.path.abspath(path), "read", "bench_metric_")
 
 
 # ---------------------------------------------------------------------------
@@ -199,9 +196,10 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool, *,
                      f"JAX sees {len(devs)} {devs[0].platform} device(s)")
     phases = {"devices": time.perf_counter() - _T0}
     import repro.carina as carina
-    from clients import CLIENTS, compile_cache_off
+    from clients import client_class, compile_cache_off
     clock = CompileClock()
-    client = CLIENTS[spec["kind"]](carina, cfg, spec, seed, chips)
+    client = client_class(spec["kind"], c["root"])(carina, cfg, spec, seed,
+                                                   chips)
     client.build()
     phases["inputs"] = time.perf_counter() - _T0
     client.warm_up()

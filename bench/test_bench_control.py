@@ -6,14 +6,15 @@ import pytest
 import control
 import reference as ref
 import run
-from shrink import ROOT, SMALL, small_cell
+from shrink import ROOT, cpu_cells, small_cell
 
-@pytest.mark.parametrize("workload", sorted(SMALL))
+
+@pytest.mark.parametrize("workload", sorted(cpu_cells()))
 def test_program_passes_and_control_fails(workload):
     import repro.carina as carina
 
     c = small_cell(workload)
-    requests = SMALL[workload][1]
+    requests = c["spec"]["cpu"]["requests"]
     rows = (control.readings(c, [3, 2 ** 31 + 11], requests, "fp64", carina)
             + control.readings(c, [3], requests, "mixed", carina))
     limit = c["spec"]["check"]["max_rel_gap"]

@@ -60,13 +60,13 @@ CONTROL = r"""
 import json
 import control
 import repro.carina as carina
-from shrink import SMALL, small_cell
+from shrink import small_cell
 
 c = small_cell("oem1-refresh-4chip")
 reads = {}
 for chips in (4, 1):
     c["cell"]["chips"] = chips
-    reads[chips] = control.readings(c, [7], SMALL["oem1-refresh-4chip"][1],
+    reads[chips] = control.readings(c, [7], c["spec"]["cpu"]["requests"],
                                     "mixed", carina)[0]["checks"]
 print(json.dumps(reads))
 """

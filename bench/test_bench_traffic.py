@@ -44,13 +44,16 @@ def test_same_seed_same_inputs():
                                                                0)
 
 
-def test_forecasts_move_on_so_no_refresh_hits_the_plan_memo():
+def test_forecasts_move_on_and_carbon_blind_candidates_hit_the_plan_memo():
+    """Each refresh brings a new forecast, one day on; the candidates'
+    plans do not read carbon, so the second refresh finds all six in the
+    program's plan memo."""
     import repro.carina as carina
 
     cfg, spec = load("configs", "oem1-campaign"), load("traffic", "refresh")
     spec["candidates"]["parametric"]["count"] = 3
     spec["candidates"]["deadline"]["count"] = 3
-    d = clients.Refresh(carina, cfg, spec, 99, 1)
+    d = clients.client_class("refresh")(carina, cfg, spec, 99, 1)
     d.build()
     m1, m2 = d.members(1), d.members(2)
     assert m1.shape == m2.shape == (51, 336)
@@ -60,7 +63,7 @@ def test_forecasts_move_on_so_no_refresh_hits_the_plan_memo():
     carina.reset_scan_stats()
     d.request(2)
     st = carina.scan_stats()
-    assert st.plan_hits == 0 and st.plan_misses == 6
+    assert st.plan_hits == 6 and st.plan_misses == 0
 
 
 def test_four_chip_mix_is_the_one_chip_mix():
@@ -100,12 +103,8 @@ def test_harness_fails_without_the_program(tmp_path):
 def test_new_traffic_and_cell_are_found_by_name(tmp_path):
     """A mix, a cell and a per-layer metric added as new files and new
     manifest entries run with no edit to any existing file."""
-    shutil.copytree(os.path.join(BENCH, "configs"),
-                    tmp_path / "bench" / "configs")
-    shutil.copytree(os.path.join(BENCH, "traffic"),
-                    tmp_path / "bench" / "traffic")
-    shutil.copytree(os.path.join(BENCH, "metrics"),
-                    tmp_path / "bench" / "metrics")
+    for part in ("configs", "traffic", "kinds", "metrics"):
+        shutil.copytree(os.path.join(BENCH, part), tmp_path / "bench" / part)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     small = load("traffic", "refresh")

@@ -44,7 +44,7 @@ def test_padding_does_not_change_the_work():
         spec = json.load(f)
     spec["candidates"]["parametric"]["count"] = 3
     spec["candidates"]["deadline"]["count"] = 2
-    d = clients.Refresh(carina, cfg, spec, 7, 1)
+    d = clients.client_class("refresh")(carina, cfg, spec, 7, 1)
     d.build()
     carina.reset_scan_stats()
     d.request(1)
@@ -55,7 +55,7 @@ def test_padding_does_not_change_the_work():
     assert work["members"] == 51
     assert padded > work["lane_slots"]
     # the same five cases in a batch padded differently: same work
-    d2 = clients.Refresh(carina, cfg, spec, 7, 1)
+    d2 = clients.client_class("refresh")(carina, cfg, spec, 7, 1)
     d2.build()
     d2.schedules = d2.schedules + d2.schedules[:1]   # six lanes, pad to 8
     d2.request(1)
