@@ -139,7 +139,11 @@ class ScanStats:
     sweep shows `disk_hits == S` with `plan_misses == 0` — zero
     classification/lowering work), and `lanes_recomputed`/
     `lanes_spliced` partition a `delta_sweep`'s lanes into re-scanned
-    vs result-spliced (a 1-of-S schedule change shows ~1/S recomputed).
+    vs result-spliced (a 1-of-S schedule change shows ~1/S recomputed);
+    `lanes_relowered` counts the lanes whose decision-table rows
+    `replace_tables` wrote into the plan's stacked tables (the changed
+    cases' lanes, or every lane when the stack's bucket width changes
+    and it is rebuilt whole).
     Counters accumulate per process — pass `scan_stats(reset=True)`
     (or call `reset_scan_stats()`) to zero them between measurements.
     """
@@ -155,6 +159,7 @@ class ScanStats:
     disk_misses: int = 0          # disk lookups that fell through to compile
     lanes_recomputed: int = 0     # delta_sweep lanes re-scanned
     lanes_spliced: int = 0        # delta_sweep lanes served from prev results
+    lanes_relowered: int = 0      # lanes whose stacked table rows were written
     requests_seen: int = 0        # requests offered to the serving layer
     requests_admitted: int = 0    # ... assigned a service slot
     requests_rejected: int = 0    # ... infeasible at every allowed tier
@@ -853,6 +858,39 @@ def new_cursor(plan: SweepPlan) -> PlanCursor:
     return PlanCursor(state=state, t0=0, active=np.arange(L))
 
 
+def _table_width(tables) -> int:
+    """Progress buckets of the widest of these decision tables (1 when
+    there is none): the bucket axis of a plan's stacked tables."""
+    return max((t[0].shape[1] for t in tables if t is not None), default=1)
+
+
+def _write_tables(tab_u: np.ndarray, tab_b: np.ndarray, lane_table,
+                  lanes) -> None:
+    """Write the decision-table rows of `lanes` into the stacked tables,
+    each table broadcast across the stack's bucket axis; a lane with no
+    table (chunk-built) gets the stack's zero/one filler rows."""
+    shape = tab_u.shape[1:]
+    for lane in lanes:
+        t = lane_table[lane]
+        if t is None:
+            tab_u[lane], tab_b[lane] = 0.0, 1.0
+        else:
+            tab_u[lane] = np.broadcast_to(t[0], shape)
+            tab_b[lane] = np.broadcast_to(t[1], shape)
+
+
+def _stack_tables(lane_table, H: int) -> Tuple[np.ndarray, np.ndarray]:
+    """(tab_u, tab_b), each (L, H, B_t): every lane's periodic table
+    stacked once, so the per-chunk assembly is one fancy-index slice
+    instead of a per-lane Python loop."""
+    B_t = _table_width(lane_table)
+    tab_u = np.zeros((len(lane_table), H, B_t))
+    tab_b = np.ones((len(lane_table), H, B_t))
+    _write_tables(tab_u, tab_b, lane_table,
+                  [i for i, t in enumerate(lane_table) if t is not None])
+    return tab_u, tab_b
+
+
 @_spanned("plan")
 def compile_plan(cases: Sequence, price: Optional[Signal] = None, *,
                  slots_per_hour: int = 1, progress_buckets: int = 32,
@@ -1078,27 +1116,14 @@ def compile_plan(cases: Sequence, price: Optional[Signal] = None, *,
         mach = [cases[i].machine for i in lane_case]
         start = np.array([cases[i].start_hour for i in lane_case], dtype=float)
         g0 = np.floor(start * sph) / sph
-        # periodic decision tables, stacked once so the per-chunk assembly is
-        # one fancy-index slice instead of a per-lane Python loop
-        L = len(lane_case)
-        B_t = max((t[0].shape[1] for t in lane_table if t is not None),
-                  default=1)
-        tab_u = np.zeros((L, H, B_t))
-        tab_b = np.ones((L, H, B_t))
-        for lane, t in enumerate(lane_table):
-            if t is not None:
-                u_r, b_r = t
-                tab_u[lane] = u_r if u_r.shape[1] == B_t \
-                    else np.broadcast_to(u_r, (H, B_t))
-                tab_b[lane] = b_r if b_r.shape[1] == B_t \
-                    else np.broadcast_to(b_r, (H, B_t))
+        tab_u, tab_b = _stack_tables(lane_table, H)
         return SweepPlan(
             cases=tuple(cases), price=price, sph=sph, B=B, max_days=int(max_days),
             E=E, case_ensemble=ensembles, case_expanded=case_expanded,
             lane_case=lc, lane_member=np.asarray(lane_member, dtype=int),
             lane_table=lane_table, lane_builder=lane_builder,
             lane_periodic=np.asarray(lane_periodic, dtype=bool),
-            tab_u=tab_u, tab_b=tab_b, tab_buckets=B_t,
+            tab_u=tab_u, tab_b=tab_b, tab_buckets=tab_u.shape[2],
             lane_co2_sigs=lane_co2,
             n_scen=np.array([float(w.n_scenarios) for w in wl]),
             rate=np.array([w.rate_at_full for w in wl]),
@@ -1168,6 +1193,7 @@ def _normalize_replace_maps(plan: SweepPlan, schedules, carbon
     return sched_map, carbon_map
 
 
+@_spanned("replace")
 def replace_tables(plan: SweepPlan, cursor: Optional[PlanCursor] = None, *,
                    schedules=None, carbon=None,
                    cache_dir: Optional[str] = None) -> SweepPlan:
@@ -1193,7 +1219,9 @@ def replace_tables(plan: SweepPlan, cursor: Optional[PlanCursor] = None, *,
     resolves like `compile_plan`'s); `scan_stats().replans` counts
     each call and `slots_reused` accumulates `cursor.t0 * n_lanes` — the
     lane x slot units of executed state carried forward instead of
-    recomputed.
+    recomputed.  Only the changed lanes' rows of the stacked tables are
+    written (`lanes_relowered`), on a copy: the stack is rebuilt whole
+    only when its bucket width changes.
     """
     sched_map, carbon_map = _normalize_replace_maps(plan, schedules, carbon)
     changed = sorted(set(sched_map) | set(carbon_map))
@@ -1299,26 +1327,29 @@ def replace_tables(plan: SweepPlan, cursor: Optional[PlanCursor] = None, *,
                                            for _ in range(plan.E))
             lane_periodic[lane] = comp.periodic
 
-    # restack the periodic tables (cheap NumPy; no classification)
-    L = plan.n_lanes
-    B_t = max((t[0].shape[1] for t in lane_table if t is not None),
-              default=1)
-    tab_u = np.zeros((L, H, B_t))
-    tab_b = np.ones((L, H, B_t))
-    for lane, t in enumerate(lane_table):
-        if t is not None:
-            u_r, b_r = t
-            tab_u[lane] = u_r if u_r.shape[1] == B_t \
-                else np.broadcast_to(u_r, (H, B_t))
-            tab_b[lane] = b_r if b_r.shape[1] == B_t \
-                else np.broadcast_to(b_r, (H, B_t))
+    # the stack keeps its bucket width (as a fresh compile_plan would)
+    # unless a new table is wider, or the replaced tables were as wide
+    # as the stack and the new ones are narrower: then it may narrow
+    rows = np.flatnonzero(np.isin(plan.lane_case, changed))
+    B_t = plan.tab_buckets
+    now = _table_width(lane_table[lane] for lane in rows)
+    if now > B_t or now < B_t == _table_width(plan.lane_table[lane]
+                                             for lane in rows):
+        B_t = _table_width(lane_table)
+    if B_t == plan.tab_buckets:
+        tab_u, tab_b = plan.tab_u.copy(), plan.tab_b.copy()
+        _write_tables(tab_u, tab_b, lane_table, rows)
+        _STATS.lanes_relowered += len(rows)
+    else:
+        tab_u, tab_b = _stack_tables(lane_table, H)
+        _STATS.lanes_relowered += plan.n_lanes
     # grids dict is shared by reference: unchanged signals keep their
     # incrementally-sampled prefixes, so resuming re-samples nothing
     return dataclasses.replace(
         plan, cases=tuple(new_cases), case_ensemble=ensembles,
         lane_table=lane_table, lane_builder=lane_builder,
         lane_periodic=lane_periodic, tab_u=tab_u, tab_b=tab_b,
-        tab_buckets=B_t, lane_co2_sigs=lane_co2, est_h=est_h)
+        tab_buckets=tab_u.shape[2], lane_co2_sigs=lane_co2, est_h=est_h)
 
 
 def _value_changed(old, new) -> bool:
@@ -1354,9 +1385,8 @@ def _subset_plan(plan: SweepPlan, case_idx: Sequence[int]) -> SweepPlan:
                     "share the site cap every slot")
     case_pos = {int(i): j for j, i in enumerate(idx)}
     lanes = np.flatnonzero(np.isin(plan.lane_case, idx))
-    old_groups = sorted({int(plan.case_group[i]) for i in idx})
-    gmap = {g: k for k, g in enumerate(old_groups)}
-    ga = np.asarray(old_groups, dtype=int)
+    ga, sizes = np.unique(plan.case_group[idx], return_counts=True)
+    gmap = {int(g): k for k, g in enumerate(ga)}
     return dataclasses.replace(
         plan,
         cases=tuple(plan.cases[i] for i in idx),
@@ -1375,9 +1405,7 @@ def _subset_plan(plan: SweepPlan, case_idx: Sequence[int]) -> SweepPlan:
         alpha=plan.alpha[lanes], gamma=plan.gamma[lanes],
         ohfrac=plan.ohfrac[lanes], start=plan.start[lanes],
         g0=plan.g0[lanes], s0=plan.s0[lanes], bg_day=plan.bg_day[lanes],
-        group_sizes=tuple(
-            int(np.isin(np.flatnonzero(plan.case_group == g), idx).sum())
-            for g in old_groups),
+        group_sizes=tuple(int(n) for n in sizes),
         case_group=np.array([gmap[int(plan.case_group[i])] for i in idx],
                             dtype=int),
         lane_group=np.array([gmap[int(g)] for g in plan.lane_group[lanes]],
@@ -1429,7 +1457,12 @@ def delta_sweep(prev_plan: SweepPlan, prev_results: Sequence[SimResult], *,
 
     `scan_stats().lanes_recomputed`/`lanes_spliced` account the lane
     partition; with K changed schedules out of S the re-scanned slot
-    work is ~K/S of a full re-sweep.  `cache_dir` resolves like
+    work is ~K/S of a full re-sweep.  The call writes the span
+    `carina.delta` (stats: the cases `changed` after the screen, and
+    those `recomputed` and `spliced`) around `carina.delta.screen` (the
+    value screen), `carina.replace`, `carina.delta.subset`, the
+    subplan's `carina.execute` and `carina.summarize`, and
+    `carina.delta.splice`.  `cache_dir` resolves like
     `compile_plan`'s.  Note a changed *carbon* signal affects every
     case it applies to even under carbon-blind schedules — the CO2
     integral runs over the realized trace — so a new carbon window
@@ -1442,40 +1475,47 @@ def delta_sweep(prev_plan: SweepPlan, prev_results: Sequence[SimResult], *,
         raise ValueError(
             f"prev_results carries {len(prev_results)} results but the "
             f"plan has {n} cases — pass last cycle's full result list")
-    sched_map, carbon_map = _normalize_replace_maps(prev_plan, schedules,
-                                                    carbon)
-    sched_map = {i: s for i, s in sched_map.items()
-                 if _value_changed(prev_plan.cases[i].schedule, s)}
-    carbon_map = {i: c for i, c in carbon_map.items()
-                  if _value_changed(prev_plan.cases[i].carbon, c)}
-    new_plan = replace_tables(prev_plan, None,
-                              schedules=sched_map or None,
-                              carbon=carbon_map or None,
-                              cache_dir=cache_dir)
-    affected = set(sched_map) | set(carbon_map)
-    # lane-group revalidation: a changed member of a site-capped group
-    # invalidates the whole group's scan, not just its own lane
-    for g in sorted({int(new_plan.case_group[i]) for i in affected}):
-        if np.isfinite(new_plan.group_cap_kw[g]):
-            affected.update(
-                int(i) for i in np.flatnonzero(new_plan.case_group == g))
-    if not affected:
-        _STATS.lanes_spliced += new_plan.n_lanes
-        return DeltaSweepResult(results=prev_results, plan=new_plan,
-                                recomputed=(), spliced=tuple(range(n)))
-    sub = sorted(affected)
-    subplan = _subset_plan(new_plan, sub)
-    _STATS.lanes_recomputed += subplan.n_lanes
-    _STATS.lanes_spliced += new_plan.n_lanes - subplan.n_lanes
-    state = execute_plan(subplan, backend=backend, chunk_days=chunk_days,
-                         devices=devices)
-    sub_results = summarize_plan(subplan, state)
-    results = prev_results
-    for j, i in enumerate(sub):
-        results[i] = sub_results[j]
-    return DeltaSweepResult(
-        results=results, plan=new_plan, recomputed=tuple(sub),
-        spliced=tuple(i for i in range(n) if i not in affected))
+    with _span("delta") as span:
+        with _span("delta.screen"):
+            sched_map, carbon_map = _normalize_replace_maps(
+                prev_plan, schedules, carbon)
+            sched_map = {i: s for i, s in sched_map.items()
+                         if _value_changed(prev_plan.cases[i].schedule, s)}
+            carbon_map = {i: c for i, c in carbon_map.items()
+                          if _value_changed(prev_plan.cases[i].carbon, c)}
+        new_plan = replace_tables(prev_plan, None,
+                                  schedules=sched_map or None,
+                                  carbon=carbon_map or None,
+                                  cache_dir=cache_dir)
+        changed = set(sched_map) | set(carbon_map)
+        with _span("delta.subset"):
+            # lane-group revalidation: a changed member of a site-capped
+            # group invalidates the whole group's scan, not just its lane
+            affected = set(changed)
+            for g in sorted({int(new_plan.case_group[i]) for i in changed}):
+                if np.isfinite(new_plan.group_cap_kw[g]):
+                    affected.update(int(i) for i in
+                                    np.flatnonzero(new_plan.case_group == g))
+            sub = sorted(affected)
+            subplan = _subset_plan(new_plan, sub) if sub else None
+        rescanned = subplan.n_lanes if sub else 0
+        _STATS.lanes_recomputed += rescanned
+        _STATS.lanes_spliced += new_plan.n_lanes - rescanned
+        sub_results: List[SimResult] = []
+        if sub:
+            state = execute_plan(subplan, backend=backend,
+                                 chunk_days=chunk_days, devices=devices)
+            sub_results = summarize_plan(subplan, state)
+        with _span("delta.splice"):
+            results = prev_results
+            for i, r in zip(sub, sub_results):
+                results[i] = r
+            spliced = tuple(i for i in range(n) if i not in affected)
+        if span.is_enabled():
+            span.set_metadata(changed=len(changed), recomputed=len(sub),
+                              spliced=len(spliced))
+    return DeltaSweepResult(results=results, plan=new_plan,
+                            recomputed=tuple(sub), spliced=spliced)
 
 
 # ---------------------------------------------------------------------------
@@ -1504,14 +1544,19 @@ def _sig_slice(plan: SweepPlan, sig, g0: float, t0: int,
 def _bucket_lookup(xp, u_tab, b_tab, sidx, row, prog, B):
     """Decision at live progress: linear interpolation between the two
     nearest bucket centers (tables are sampled at centers (b+0.5)/B), so
-    smooth progress-aware schedules see no quantization bias."""
+    smooth progress-aware schedules see no quantization bias.  Written
+    as u0 + w (u1 - u0), a row that is flat across buckets (a narrower
+    table broadcast to the chunk's B) reads exactly its value, as with
+    B == 1: a lane's answer does not depend on the lanes it shares a
+    chunk with (what `delta_sweep`'s subplans rely on)."""
     if B == 1:
         return u_tab[sidx, row, 0], b_tab[sidx, row, 0]
     x = prog * B - 0.5
     b0 = xp.clip(xp.floor(x), 0, B - 2).astype("int32")
     w = xp.clip(x - b0, 0.0, 1.0)
-    u = (1.0 - w) * u_tab[sidx, row, b0] + w * u_tab[sidx, row, b0 + 1]
-    bt = (1.0 - w) * b_tab[sidx, row, b0] + w * b_tab[sidx, row, b0 + 1]
+    u0, b_0 = u_tab[sidx, row, b0], b_tab[sidx, row, b0]
+    u = u0 + w * (u_tab[sidx, row, b0 + 1] - u0)
+    bt = b_0 + w * (b_tab[sidx, row, b0 + 1] - b_0)
     return u, bt
 
 

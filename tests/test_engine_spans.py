@@ -8,7 +8,9 @@ each inside its parent and every one inside its `carina.sweep`, at most
 `carina.plan.classify` carries the cases the memo served (`hits`) and
 those it had to obtain (`misses`).  `live_slot_work` counts the unpadded
 lanes of each launch, never more than `slot_work`, which counts them
-padded to their shape bucket.
+padded to their shape bucket.  A `delta_sweep` writes `carina.delta`
+around its screen, `carina.replace`, subset, the subplan's execute and
+summarize, and splice; `replace_tables` alone writes `carina.replace`.
 """
 import dataclasses
 import glob
@@ -215,3 +217,47 @@ def test_spans_of_sharded_sweeps_on_four_devices(tmp_path):
     assert out["devices_used"] == 4
     assert 0 < out["live"] <= out["slot"]
     assert len(check_spans(out["spans"])) == 2
+
+
+#: The spans of one `delta_sweep` and each one's parent.
+DELTA_PARENT = {"delta.screen": "delta", "replace": "delta",
+                "delta.subset": "delta", "execute": "delta",
+                "chunk.inputs": "execute", "chunk": "execute",
+                "chunk.upload": "chunk", "chunk.launch": "chunk",
+                "chunk.fetch": "chunk", "summarize": "delta",
+                "delta.splice": "delta"}
+
+
+def test_spans_of_a_delta_sweep_and_a_bare_replace(calibrated, tmp_path):
+    """A rescore of 2 of 6 cases writes `carina.delta` around its screen,
+    `carina.replace`, subset, the subplan's execute and summarize, and
+    splice, with the cases it screened as changed, re-scanned and
+    spliced; the MPC's bare `replace_tables` writes `carina.replace`."""
+    from repro.core.engine_jax import (compile_plan, delta_sweep,
+                                       execute_plan, replace_tables,
+                                       summarize_plan)
+
+    cases = plain_cases(calibrated, 6)
+    plan = compile_plan(cases)
+    prev = summarize_plan(plan, execute_plan(plan))
+    new = {1: constant_schedule(0.55), 4: constant_schedule(0.65),
+           5: cases[5].schedule}              # screened out: unchanged
+
+    def calls():
+        delta_sweep(plan, prev, schedules=new)
+        replace_tables(plan, schedules={0: constant_schedule(0.7)})
+
+    spans = record(calls, str(tmp_path))
+    delta, = [s for s in spans if s[2] == "delta"]
+    assert {k: delta[4][k] for k in ("changed", "recomputed", "spliced")} \
+        == {"changed": 2, "recomputed": 2, "spliced": 4}
+    inside = [s for s in spans if s is not delta and s[3] == delta[3]
+              and delta[0] <= s[0] and s[1] <= delta[1]]
+    assert {s[2] for s in inside} == set(DELTA_PARENT)
+    for s, e, name, thread, _ in inside:
+        assert any(p[2] == DELTA_PARENT[name] and p[3] == thread
+                   and p[0] <= s and e <= p[1] for p in spans), name
+    replaces = [s for s in spans if s[2] == "replace"]
+    assert len(replaces) == 2
+    assert sum(s not in inside for s in replaces) == 1
+    assert {s[2] for s in spans} == set(DELTA_PARENT) | {"delta"}

@@ -633,3 +633,75 @@ def test_replace_tables_carbon_delta_hits_for_blind_schedules(calibrated):
     ej.clear_plan_cache()
     cold = trace_sweep(_candidates(calibrated, b))
     assert _answers(replanned) == _answers(cold)
+
+
+# ---------------------------------------------------------------------------
+# The operator's rescore cycle: a few candidates revised per cycle, each
+# delta chained on the previous one's plan and results
+# ---------------------------------------------------------------------------
+def _revision(rng, i: int, n_par: int):
+    """A fresh candidate for slot i, of the family the slot holds."""
+    if i < n_par:
+        return ParametricSchedule(tuple(float(v) for v in 1.5 * rng.randn(24)),
+                                  u_min=0.45, name=f"parametric-{i}")
+    return deadline_schedule(float(rng.uniform(30.0, 90.0)),
+                             name=f"deadline-{i}")
+
+
+@pytest.mark.parametrize("backend, tol", [("numpy", 0.0), ("jax", 1e-12)],
+                         ids=["numpy-bitwise", "jax-1e-12"])
+def test_chained_rescores_equal_full_sweeps(calibrated, backend, tol):
+    """16 candidates (8 day schedules, 8 pace keepers) under a 4-member
+    forecast; three cycles each revise 3 of them through `delta_sweep`,
+    chained on the previous cycle.  Every cycle answers as a full sweep
+    of its candidate set, re-scans the 3 revised lanes only, splices the
+    other 13, and re-lowers the stacked table rows of the 3 alone."""
+    wl, m = calibrated
+    wl = dataclasses.replace(wl, n_scenarios=300_000.0)
+    rng = np.random.RandomState(2024)
+    n_par, S = 8, 16
+    forecast = _forecast(5, members=4, hours=24 * 8)
+    cases = [SweepCase(_revision(rng, i, n_par), wl, m, carbon=forecast)
+             for i in range(S)]
+    plan = ej.compile_plan(cases)
+    results = ej.summarize_plan(plan, ej.execute_plan(
+        plan, backend=backend, chunk_days=1))
+    # both families, day schedules alone, pace keepers alone
+    for cycle, revised in enumerate(([1, 6, 12], [0, 2, 5], [9, 13, 15])):
+        new = {i: _revision(rng, i, n_par) for i in revised}
+        ej.reset_scan_stats()
+        delta = ej.delta_sweep(plan, results, schedules=new,
+                               backend=backend, chunk_days=1)
+        s = ej.scan_stats()
+        assert delta.recomputed == tuple(revised)
+        assert (s.lanes_recomputed, s.lanes_spliced, s.lanes_relowered) \
+            == (3, S - 3, 3)
+        cases = [dataclasses.replace(c, schedule=new[i]) if i in new else c
+                 for i, c in enumerate(cases)]
+        full = trace_sweep(cases, backend=backend, chunk_days=1)
+        got, want = (np.array([(r.runtime_h, r.energy_kwh, r.co2_kg,
+                                *r.co2_ensemble.samples) for r in res])
+                     for res in (delta.results, full))
+        assert np.all(np.abs(got - want) <= tol * np.abs(want)), cycle
+        plan, results = delta.plan, delta.results
+
+
+def test_replace_tables_restacks_whole_when_the_bucket_width_changes(
+        calibrated):
+    """A progress-aware replacement widens the stacked tables' bucket
+    axis, and replacing it back narrows them again: both restack every
+    lane, and the stack is the one a cold compile of the cases builds."""
+    cases = _cases(calibrated, 3)
+    plan = ej.compile_plan(cases)
+    ramp = FunctionSchedule("ramp", lambda ctx: 0.3 + 0.6 * ctx.progress)
+    ej.reset_scan_stats()
+    wide = ej.replace_tables(plan, schedules={1: ramp})
+    narrow = ej.replace_tables(wide, schedules={1: cases[1].schedule})
+    assert (plan.tab_buckets, wide.tab_buckets, narrow.tab_buckets) == \
+        (1, 32, 1)
+    assert ej.scan_stats().lanes_relowered == 6
+    for p in (wide, narrow):
+        cold = ej.compile_plan(p.cases)
+        assert np.array_equal(p.tab_u, cold.tab_u)
+        assert np.array_equal(p.tab_b, cold.tab_b)
+    assert plan.tab_u.shape[2] == 1, "the incumbent plan is left as it was"
